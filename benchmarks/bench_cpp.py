@@ -60,6 +60,7 @@ def run_cpp_lowering(S: int, chunk: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"     # virtual devices: a CPU-only lane
     res = subprocess.run([sys.executable, "-c", _SUB, str(S), str(chunk)],
                          env=env, capture_output=True, text=True,
                          timeout=3000)

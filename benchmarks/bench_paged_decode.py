@@ -282,6 +282,7 @@ def _mesh_table():
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"     # virtual devices: a CPU-only lane
     res = subprocess.run([sys.executable, "-c", _MESH_SUB], env=env,
                          capture_output=True, text=True, timeout=900)
     if res.returncode != 0:

@@ -45,6 +45,7 @@ def run_one(arch: str, shape: str, multi_pod: bool = False,
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)   # dryrun sets its own
+    env["JAX_PLATFORMS"] = "cpu"     # 512 virtual devices: a CPU-only lane
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--json-out", out]
     if multi_pod:

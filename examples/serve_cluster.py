@@ -21,7 +21,10 @@ serves a query extending ANOTHER node's document — a cross-process
 socket fetch — and the parent checks every decoded token bit-exact
 against a single-process DRAM-only oracle. ``--chaos kill-owner``
 SIGKILLs the block owner mid-transfer; survivors must still match the
-oracle, with the degradation accounted in ``fallback_reasons``.
+oracle, with the degradation accounted in ``fallback_reasons``. A chip
+belongs to one process, so this lane runs on the CPU by design: every
+worker is started with ``JAX_PLATFORMS=cpu`` and says so, and the parent
+pins its own jax to the CPU and touches it only after the workers exit.
 
     PYTHONPATH=src python examples/serve_cluster.py [--requests 24]
     PYTHONPATH=src python examples/serve_cluster.py --ssd-blocks 64 \
@@ -85,6 +88,8 @@ def _worker_main(args) -> int:
     from repro.serving.transport import BlockServer, InProcPeer, SocketPeer
 
     n, i = args.processes, args.worker_node
+    print(f"node {i}: jax on {jax.devices()[0].platform} (one chip cannot "
+          f"be shared by {n} processes)", flush=True)
     cfg = get_config("smollm-360m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     docs, queries = _cluster_workload(n, cfg.vocab_size)
@@ -142,32 +147,39 @@ def _worker_main(args) -> int:
     return 0
 
 
+def _oracle(cfg, queries, max_new: int) -> dict:
+    """Single-process DRAM-only decode of every query."""
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    opool = HostKVPool(capacity_blocks=4096)
+    try:
+        opw = PrefillWorker(params, cfg, opool, prefill_chunk=256)
+        odw = DecodeWorker(params, cfg, max_batch=1, max_len=2048,
+                           substrate="dense")
+        return {i: _decode_all(params, cfg, opw, odw, q, max_new)
+                for i, q in enumerate(queries)}
+    finally:
+        opool.close()
+
+
 def _parent_main(args) -> int:
     """Launch N worker processes around an in-process DirectoryServer,
     optionally kill -9 the block owner mid-transfer, and hold every
-    surviving answer bit-exact against a single-process oracle."""
+    surviving answer bit-exact against a single-process oracle. The
+    workers run on the CPU; so does the oracle, computed only after they
+    have exited (the parent never holds a device while they run)."""
     import shutil
     import tempfile
 
     from repro.serving.directory_service import (DirectoryServer,
                                                  RemoteDirectory)
 
+    jax.config.update("jax_platforms", "cpu")   # same backend as workers
     n = args.processes
     chaos = args.chaos == "kill-owner"
     stall = args.serve_stall if args.serve_stall is not None else \
         (0.2 if chaos else 0.0)
     cfg = get_config("smollm-360m").reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
     docs, queries = _cluster_workload(n, cfg.vocab_size)
-
-    # single-process DRAM-only oracle for every query
-    opool = HostKVPool(capacity_blocks=4096)
-    opw = PrefillWorker(params, cfg, opool, prefill_chunk=256)
-    odw = DecodeWorker(params, cfg, max_batch=1, max_len=2048,
-                       substrate="dense")
-    oracle = {i: _decode_all(params, cfg, opw, odw, queries[i], args.max_new)
-              for i in range(n)}
-    opool.close()
 
     dserver = DirectoryServer()
     base = args.ssd_dir or tempfile.mkdtemp(prefix="serve-cluster-")
@@ -177,6 +189,7 @@ def _parent_main(args) -> int:
                                        "src"))
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
     procs = []
     print(f"cluster: directory @ 127.0.0.1:{dserver.port}, "
           f"{n} worker processes"
@@ -240,7 +253,12 @@ def _parent_main(args) -> int:
                 print(f"FAIL: node {i} exited {p.returncode}:\n{out}",
                       flush=True)
                 failures += 1
+            for line in out.splitlines():
+                if line.startswith(f"node {i}: jax on"):
+                    print(line, flush=True)
 
+        # every worker has exited: only now does the parent touch jax
+        oracle = _oracle(cfg, queries, args.max_new)
         survivors = range(1 if chaos else 0, n)
         reasons: dict = {}
         for i in survivors:

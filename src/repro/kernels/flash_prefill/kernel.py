@@ -8,7 +8,10 @@ step fetches its kv-head's block — no materialised head expansion).
 
 Tiling: grid (B, H, nq, nk) with the K loop as the innermost sequential
 dimension; VMEM scratch (acc, m, l) persists across the nk steps of one
-(b, h, iq) tile. Block sizes default to the MXU-native 128×128; the
+(b, h, iq) tile. The kernel runs head-major — (B, H, S, D), transposed
+in the wrapper — so every block's trailing two dims are (rows, D): Mosaic
+tiles those by (8, 128) or takes them whole, and a size-1 head block in
+front of D (the token-major layout) is refused at any real width. Block sizes default to the MXU-native 128×128; the
 working set per step is q(BQ·D) + k,v(2·BK·D) + acc(BQ·D fp32) ≈ 160 KiB
 at D=128 — comfortably inside the ~16 MiB VMEM budget, leaving room for
 double buffering.
@@ -22,12 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _tpu_params(dimension_semantics):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):   # older/newer API spellings
-        return dict(dimension_semantics=dimension_semantics)
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -46,9 +43,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)        # (BQ, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # (BK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)              # (BQ, D)
+    k = k_ref[0, 0].astype(jnp.float32)              # (BK, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     qpos = q_offset + iq * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, bk), 0)
@@ -75,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ik == nk - 1)
     def _finalize():
         den = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / den[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / den[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("q_offset", "window", "bq",
@@ -95,25 +92,22 @@ def flash_prefill(q, k, v, *, q_offset: int = 0, window: int = 0,
         _flash_kernel, scale=1.0 / (D ** 0.5), q_offset=q_offset,
         window=window, bq=bq, bk=bk, nk=nk)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, iq, ik, g=group: (b, h // g, ik, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),   # acc
             pltpu.VMEM((bq, 1), jnp.float32),   # running max m
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
         ],
-        compiler_params=_tpu_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

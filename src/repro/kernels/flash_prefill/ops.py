@@ -1,13 +1,11 @@
 """Public op: chunked-prefill flash attention (jit wrapper + dispatch).
 
-``use_pallas`` selects the Pallas kernel (TPU target; interpret=True on
-CPU for validation) vs the pure-jnp oracle. The model forward defaults to
-the oracle so the dry-run lowers cleanly on the CPU backend; on TPU the
-flag flips the hot path to the kernel.
+``use_pallas`` selects the Pallas kernel (TPU target) vs the pure-jnp
+oracle; ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
+validation) and is only ever the caller's choice. The model forward
+defaults to the oracle so the dry-run lowers cleanly on the CPU backend.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.flash_prefill.kernel import flash_prefill as _kernel
 from repro.kernels.flash_prefill.ref import flash_prefill_ref as _ref
@@ -15,12 +13,10 @@ from repro.kernels.flash_prefill.ref import flash_prefill_ref as _ref
 
 def flash_prefill_attention(q, k, v, *, q_offset: int = 0, window: int = 0,
                             use_pallas: bool = False,
-                            interpret: bool | None = None):
+                            interpret: bool = False):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D) → (B, Sq, H, D)."""
     if not use_pallas:
         return _ref(q, k, v, q_offset=q_offset, window=window)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     # largest MXU-friendly block that divides the sequence (tests sweep
     # tiny/ragged shapes; production shapes take the full 128)
     def block(s: int) -> int:

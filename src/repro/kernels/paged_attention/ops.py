@@ -1,4 +1,9 @@
-"""Public op: paged decode attention (kernel or oracle dispatch)."""
+"""Public op: paged decode attention (kernel or oracle dispatch).
+
+``use_pallas`` is the caller's choice and is honoured or refused, never
+quietly swapped: a case the kernel cannot take raises, and ``interpret``
+(the Pallas interpreter, for CPU validation) is only ever what the caller
+passes."""
 from __future__ import annotations
 
 import jax
@@ -9,23 +14,27 @@ from repro.kernels.paged_attention.ref import (
     paged_attention_layers_ref as _ref_layers, paged_attention_ref as _ref)
 
 
-def _kernel_ok(q_heads: int, kv_heads: int, qh2kv, window: int) -> bool:
+def _check_kernel_case(q_heads: int, kv_heads: int, qh2kv,
+                       window: int) -> None:
     """The Pallas grid packs grouped GQA only: divisible heads, no padded
-    query-head remap, full attention. Everything else takes the oracle."""
-    return qh2kv is None and window == 0 and q_heads % kv_heads == 0
+    query-head remap, full attention. Anything else is the oracle's, and
+    asking the kernel for it is an error."""
+    if qh2kv is not None or window or q_heads % kv_heads:
+        raise ValueError(
+            f"the Pallas paged kernel takes grouped GQA with full attention "
+            f"only (H={q_heads}, KV={kv_heads}, padded head map="
+            f"{qh2kv is not None}, window={window}); pass use_pallas=False")
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
                            qh2kv=None, window: int = 0,
                            use_pallas: bool = False,
-                           interpret: bool | None = None):
+                           interpret: bool = False):
     """q: (B, H, D) over one layer's paged KV → (B, H, D)."""
-    if not use_pallas or not _kernel_ok(q.shape[1], k_pages.shape[2],
-                                        qh2kv, window):
+    if not use_pallas:
         return _ref(q, k_pages, v_pages, block_table, seq_lens,
                     qh2kv=qh2kv, window=window)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    _check_kernel_case(q.shape[1], k_pages.shape[2], qh2kv, window)
     return _kernel(q, k_pages, v_pages, block_table, seq_lens,
                    interpret=interpret)
 
@@ -33,7 +42,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
 def paged_decode_attention_sharded(q, k_pages, v_pages, block_table,
                                    seq_lens, *, mesh, window: int = 0,
                                    use_pallas: bool = False,
-                                   interpret: bool | None = None):
+                                   interpret: bool = False):
     """Mesh entry: one layer's paged decode attention shard_mapped over a
     (data, model) mesh — batch rows over 'data', KV-head stripes (and the
     grouped query heads that attend them) over 'model'. The inner loop is
@@ -45,7 +54,6 @@ def paged_decode_attention_sharded(q, k_pages, v_pages, block_table,
     ``DevicePagePool(mesh=…)`` additionally banks pages per data shard
     and hands the step bank-local tables."""
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import compat_shard_map
     m = int(mesh.shape.get("model", 1))
     H, KV = q.shape[1], k_pages.shape[2]
     assert H % KV == 0 and KV % m == 0, \
@@ -56,7 +64,7 @@ def paged_decode_attention_sharded(q, k_pages, v_pages, block_table,
                                       use_pallas=use_pallas,
                                       interpret=interpret)
 
-    f = compat_shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("data", "model", None), P(None, None, "model", None),
                   P(None, None, "model", None), P("data", None), P("data")),
@@ -67,15 +75,13 @@ def paged_decode_attention_sharded(q, k_pages, v_pages, block_table,
 def paged_decode_attention_layers(qs, k_pages, v_pages, block_table,
                                   seq_lens, *, qh2kv=None, window: int = 0,
                                   use_pallas: bool = False,
-                                  interpret: bool | None = None):
+                                  interpret: bool = False):
     """Batched-over-layers variant: qs (L, B, H, D) over the stacked
     (L, P, page, KV, D) store → (L, B, H, D). One kernel launch covers
     every layer (microbench / layer-parallel callers)."""
-    if not use_pallas or not _kernel_ok(qs.shape[2], k_pages.shape[3],
-                                        qh2kv, window):
+    if not use_pallas:
         return _ref_layers(qs, k_pages, v_pages, block_table, seq_lens,
                            qh2kv=qh2kv, window=window)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    _check_kernel_case(qs.shape[2], k_pages.shape[3], qh2kv, window)
     return _kernel_layers(qs, k_pages, v_pages, block_table, seq_lens,
                           interpret=interpret)

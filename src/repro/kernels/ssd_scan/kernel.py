@@ -112,14 +112,8 @@ def ssd_scan(x, dt, A, B, C, h0=None, *, chunk: int = 256,
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_t, dt_t, A.astype(jnp.float32), B, C, h0)
     return y.transpose(0, 2, 1, 3), hT
-
-
-def _tpu_params(dimension_semantics):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):
-        return dict(dimension_semantics=dimension_semantics)

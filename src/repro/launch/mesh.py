@@ -8,47 +8,28 @@ carries only data/pipeline parallelism, never weight sharding (the paper's
 Defined as FUNCTIONS so importing this module never touches jax device
 state (device count is locked at first jax init; the dry-run sets
 ``xla_force_host_platform_device_count=512`` before importing jax).
-
-Supports jax >= 0.4.35 (first release with ``jax.make_mesh``):
-``jax.sharding.AxisType`` only exists from 0.5, so ``compat_make_mesh``
-passes ``axis_types`` only where available — Auto is the default there
-anyway, and pre-0.5 meshes are implicitly Auto.
+Every mesh uses Auto axis types: sharding is propagated by the compiler
+outside ``jax.shard_map`` regions.
 """
 from __future__ import annotations
 
 import jax
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: the top-level alias and its
-    ``check_vma`` kwarg arrived post-0.4.37; before that it lives in
-    ``jax.experimental.shard_map`` with the kwarg spelled ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis_types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else {
-        "axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU unit tests (requires forced host device count)."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_arg(spec: str) -> tuple[int, int]:
@@ -66,9 +47,11 @@ def parse_mesh_arg(spec: str) -> tuple[int, int]:
 
 def make_decode_mesh(data: int, model: int):
     """(data, model) mesh for the sharded paged decode engine. Unlike
-    ``compat_make_mesh`` this takes the FIRST data*model devices rather
+    ``make_test_mesh`` this takes the FIRST data*model devices rather
     than requiring an exact device-count match, so ``--mesh 2x2`` works
-    on any host with >= 4 (virtual) devices."""
+    on any host with >= 4 (virtual) devices. With exactly data*model
+    devices ``jax.make_mesh`` orders them by the physical topology (a
+    2x2 v5e host gets its ICI neighbours along each axis)."""
     import numpy as np
     need = data * model
     devs = jax.devices()
@@ -79,14 +62,14 @@ def make_decode_mesh(data: int, model: int):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"before the first jax import")
     if len(devs) == need:
-        return compat_make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((data, model), ("data", "model"))
     return jax.sharding.Mesh(
         np.asarray(devs[:need]).reshape(data, model), ("data", "model"))
 
 
 def make_stage_mesh(stages: int):
     """CPP pipeline mesh (§5.1): one axis of prefill-group stages."""
-    return compat_make_mesh((stages,), ("stage",))
+    return _auto_mesh((stages,), ("stage",))
 
 
 def batch_axes_of(mesh) -> tuple:

@@ -1,9 +1,12 @@
 """Serving launcher: a single-host disaggregated Mooncake instance pair.
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-        --requests 16 [--trace trace.jsonl]
+        --requests 16 [--trace trace.jsonl] [--widths published]
 
-Runs the REAL engines (reduced model on CPU). By default requests flow
+Runs the REAL engines: the reduced model by default (CPU tests and CI),
+or the arch at its published widths with ``--widths published`` (a chip
+run; ``chip_smoke.py`` drives the same ``build_engine`` /
+``serve_requests`` path). By default requests flow
 through the always-on ``ServingLoop``: a thread feeds arrivals, prefill
 chunks interleave between continuous-batching decode steps, and admission
 backpressure sheds load when the queue/slots/page pool saturate — the §3
@@ -19,12 +22,91 @@ from __future__ import annotations
 
 import argparse
 import sys
+import threading
 import time
+
+
+def build_engine(params, cfg, pool, *, max_batch: int, max_len: int = 2048,
+                 substrate: str = "paged", mesh=None,
+                 prefill_workers: int = 1, prefill_chunk: int = 256,
+                 ssd_mode: str = "overlap", width_buckets: int = 1):
+    """The serving engine of one instance: ``prefill_workers``
+    ``PrefillWorker``s over the host pool ``pool`` and one
+    ``DecodeWorker``, sharing one ``DevicePagePool`` on the paged
+    substrate (the zero-copy §3 handoff). Returns ``(prefill_workers,
+    decode_worker)``; the page pool, sized by the decode worker from
+    ``max_batch`` and ``max_len``, is ``decode_worker.page_pool``."""
+    from repro.serving.engine import DecodeWorker, PrefillWorker
+    dw = DecodeWorker(params, cfg, max_batch=max_batch, max_len=max_len,
+                      substrate=substrate, mesh=mesh,
+                      width_buckets=width_buckets)
+    pws = [PrefillWorker(params, cfg, pool, prefill_chunk=prefill_chunk,
+                         ssd_mode=ssd_mode, page_pool=dw.page_pool)
+           for _ in range(prefill_workers)]
+    return pws, dw
+
+
+def trace_requests(cfg, n: int, *, seed: int, max_new: int,
+                   max_input: int = 1536, align: int = 1,
+                   trace_path=None) -> list:
+    """``n`` ``ServingRequest``s from a Mooncake-format trace (loaded, or
+    generated from ``seed``), realised to tokens whose 512-token blocks
+    follow the trace's hash chains, so prefix sharing is the trace's.
+    Prompts are cut to ``max_input`` tokens and, with ``align`` > 1,
+    rounded up to a multiple of it (fewer distinct prefill shapes, so
+    fewer compiles)."""
+    from repro.core.trace import (BLOCK_TOKENS, TraceSpec, generate_trace,
+                                  load_trace)
+    from repro.data.pipeline import realize_request_tokens
+    from repro.serving.request import ServingRequest
+    if trace_path:
+        reqs = load_trace(trace_path, limit=n)
+    else:
+        spec = TraceSpec(n_requests=n, duration_ms=10_000, seed=seed,
+                         max_input_tokens=2048, chat_turn_mu=5.0,
+                         doc_len_mu=6.5)
+        reqs = generate_trace(spec)[:n]
+    out = []
+    for r in reqs:
+        length = min(-(-r.input_length // align) * align, max_input)
+        r.input_length = length
+        r.hash_ids = r.hash_ids[:max(length // BLOCK_TOKENS, 1)]
+        out.append(ServingRequest(
+            req_id=r.req_id, tokens=realize_request_tokens(r, cfg.vocab_size),
+            max_new=min(max_new, max(r.output_length, 2)),
+            session=r.hash_ids[0] if r.hash_ids else None))
+    return out
+
+
+def serve_requests(pws, dw, requests, **loop_kw):
+    """Serve ``requests`` through a ``ServingLoop`` fed from a client
+    thread (``submit`` as a client calls it). Returns ``(loop stats,
+    {req_id: RequestOutput})``."""
+    from repro.serving.loop import ServingLoop
+    loop = ServingLoop(pws, dw, **loop_kw)
+
+    def feeder():
+        for r in requests:
+            loop.submit(r)
+        loop.close_intake()
+
+    th = threading.Thread(target=feeder, name="repro-loop-feeder")
+    th.start()
+    try:
+        stats = loop.run()
+    finally:
+        th.join()
+    return stats, loop.outputs
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="smollm-360m")
+    ap.add_argument("--widths", default="reduced",
+                    choices=("reduced", "published"),
+                    help="reduced: the arch's 2-layer smoke size (CPU "
+                         "tests, CI); published: its published widths and "
+                         "depth (run on the chip)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--trace", default=None)
     ap.add_argument("--max-batch", type=int, default=8)
@@ -93,15 +175,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    import numpy as np
 
     from repro.configs.base import get_config
-    from repro.core.trace import TraceSpec, generate_trace, load_trace
-    from repro.data.pipeline import realize_request_tokens
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.transformer import init_params
-    from repro.serving.engine import DecodeWorker, HostKVPool, PrefillWorker
+    from repro.serving.engine import HostKVPool
 
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.widths == "reduced":
+        cfg = cfg.reduced()
     mesh = None
     mesh_d = 1
     if args.mesh:
@@ -113,7 +196,8 @@ def main(argv=None) -> int:
             ap.error("--mesh shards the PAGED decode engine; drop "
                      "--decode-substrate dense")
         mesh_d, mesh_m = parse_mesh_arg(args.mesh)
-        if mesh_m > 1 and paged_shard_reason(cfg, mesh_m, mesh_d):
+        if mesh_m > 1 and args.widths == "reduced" \
+                and paged_shard_reason(cfg, mesh_m, mesh_d):
             kv = max(4, mesh_m)
             if 16 % kv or kv % mesh_m:
                 ap.error(f"--mesh model axis {mesh_m} has no grouped-GQA "
@@ -145,65 +229,23 @@ def main(argv=None) -> int:
                       directory=directory, node_id=0)
     if peer_pool is not None:
         pool.add_peer(1, peer_pool)
-    max_len = 2048
-    page_pool = None
-    from repro.serving.engine import paged_supported
-    if args.decode_substrate == "paged" and paged_supported(cfg):
-        from repro.serving.paged_cache import DevicePagePool
-        per_seq = max_len // 64
-        page_pool = DevicePagePool(
-            cfg, n_pages=1 + (args.max_batch // mesh_d + 1) * per_seq,
-            page_tokens=64, mesh=mesh)
-    pw = PrefillWorker(params, cfg, pool, prefill_chunk=256,
-                       ssd_mode=args.ssd_mode, page_pool=page_pool)
-
-    if args.trace:
-        reqs = load_trace(args.trace, limit=args.requests)
-    else:
-        spec = TraceSpec(n_requests=args.requests, duration_ms=10_000,
-                         seed=args.seed, max_input_tokens=2048,
-                         chat_turn_mu=5.0, doc_len_mu=6.5)
-        reqs = generate_trace(spec)[:args.requests]
-    # scale lengths to smoke size
-    for r in reqs:
-        r.input_length = min(r.input_length, 1536)
-        r.hash_ids = r.hash_ids[:max(r.input_length // 512, 1)]
-
-    dw = DecodeWorker(params, cfg, max_batch=args.max_batch, max_len=max_len,
-                      substrate=args.decode_substrate, page_pool=page_pool,
-                      width_buckets=args.width_buckets)
-    payloads = [(r.req_id, realize_request_tokens(r, cfg.vocab_size),
-                 min(args.max_new, max(r.output_length, 2)),
-                 r.hash_ids[0] if r.hash_ids else None) for r in reqs]
-    pws = [pw]
+    pws, dw = build_engine(
+        params, cfg, pool, max_batch=args.max_batch,
+        substrate=args.decode_substrate, mesh=mesh,
+        prefill_workers=1 if args.no_loop else args.prefill_workers,
+        ssd_mode=args.ssd_mode, width_buckets=args.width_buckets)
+    pw, page_pool = pws[0], dw.page_pool
+    requests = trace_requests(cfg, args.requests, seed=args.seed,
+                              max_new=args.max_new, trace_path=args.trace)
     t0 = time.time()
     if not args.no_loop:
-        import threading
-
-        from repro.serving.loop import ServingLoop
-        from repro.serving.request import ServingRequest
-        pws += [PrefillWorker(params, cfg, pool, prefill_chunk=256,
-                              ssd_mode=args.ssd_mode, page_pool=page_pool)
-                for _ in range(args.prefill_workers - 1)]
-        loop = ServingLoop(pws, dw, tbt_budget_s=args.tbt_budget,
-                           chunks_per_iter=args.chunks_per_iter,
-                           max_queue=max(args.requests, 8),
-                           admission=args.admission,
-                           preempt=not args.no_preempt,
-                           restore_mode=args.restore_mode)
-
-        def feeder():
-            for rid, toks, mn, sess in payloads:
-                loop.submit(ServingRequest(req_id=rid, tokens=toks,
-                                           max_new=mn, session=sess))
-            loop.close_intake()
-
-        th = threading.Thread(target=feeder, name="repro-loop-feeder")
-        th.start()
-        ls = loop.run()
-        th.join()
+        ls, outputs = serve_requests(
+            pws, dw, requests, tbt_budget_s=args.tbt_budget,
+            chunks_per_iter=args.chunks_per_iter,
+            max_queue=max(args.requests, 8), admission=args.admission,
+            preempt=not args.no_preempt, restore_mode=args.restore_mode)
         done = ls["completed"]
-        total_new = sum(len(o.tokens) for o in loop.outputs.values())
+        total_new = sum(len(o.tokens) for o in outputs.values())
         print(f"loop: {ls['iterations']} iterations, {ls['decode_steps']} "
               f"decode steps, {ls['prefill_chunks']} prefill chunks "
               f"interleaved, {ls['rejected']} rejected by "
@@ -213,15 +255,14 @@ def main(argv=None) -> int:
               f"{ls['tbt_p50_s'] * 1e3:.1f}/{ls['tbt_p99_s'] * 1e3:.1f} ms")
     else:
         done, total_new = 0, 0
-        queue = list(payloads)
+        queue = list(requests)
         outputs: dict = {}
-        from repro.serving.request import ServingRequest
         while queue or dw.n_active:
             while queue and dw.n_active < args.max_batch:
-                rid, toks, mn, sess = queue.pop(0)
-                pres = pw(toks, session=sess)
-                dw.join(ServingRequest(req_id=rid, tokens=toks, max_new=mn,
-                                       session=sess), pres)
+                req = queue.pop(0)
+                pres = pw(req.tokens, session=req.session)
+                dw.join(req, pres)
+                rid = req.req_id
                 outputs[rid] = [pres.first_token]
                 print(f"req {rid:4d}: prefill {pres.prompt_len:5d} tokens, "
                       f"reused {pres.reused_blocks} blocks, "

@@ -30,9 +30,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.configs.base import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.layers import Dist, NO_DIST
     from repro.training.loop import train
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -40,10 +40,11 @@ SEQ_SHARDED_RESIDUAL = __import__("os").environ.get(
     "REPRO_SEQ_SHARDED", "1") == "1"
 
 # Pallas hot path: route prefill-attention chunks through the
-# flash_prefill kernel (kernels/flash_prefill). Default off on this CPU
-# rig (interpret mode is for validation, not speed); on TPU flip it on.
-USE_PALLAS_ATTN = __import__("os").environ.get(
-    "REPRO_USE_PALLAS", "0") == "1"
+# flash_prefill kernel (kernels/flash_prefill). Off by default; "1" runs
+# the compiled kernel (TPU), "interpret" the Pallas interpreter (CPU
+# validation only — it is for correctness, not speed).
+_PALLAS_ATTN = __import__("os").environ.get("REPRO_USE_PALLAS", "0")
+USE_PALLAS_ATTN = _PALLAS_ATTN in ("1", "interpret")
 
 # Token count at/below which MoE uses the global (pjit-propagated) dispatch;
 # above it, the shard_map expert-parallel path (decode steps are tiny,
@@ -186,8 +187,9 @@ def causal_attention(q, k, v, q_offset, window: int = 0,
     if USE_PALLAS_ATTN and Sq % 16 == 0 and Sk % 16 == 0 \
             and isinstance(q_offset, int) and D in (32, 64, 128, 256):
         from repro.kernels.flash_prefill.ops import flash_prefill_attention
-        return flash_prefill_attention(q, k, v, q_offset=q_offset,
-                                       window=window, use_pallas=True)
+        return flash_prefill_attention(
+            q, k, v, q_offset=q_offset, window=window, use_pallas=True,
+            interpret=_PALLAS_ATTN == "interpret")
 
     if Sq <= chunk_q:
         qpos = q_offset + jnp.arange(Sq)
@@ -381,7 +383,7 @@ def paged_attention_block(x, p, cfg: ModelConfig, dist: Dist, *,
     page is exclusively owned (copy-on-write happens before the step), so
     the scatter never mutates a page another slot can read.
 
-    ``shard`` (inside ``compat_shard_map`` only): KV heads are striped
+    ``shard`` (inside ``jax.shard_map`` only): KV heads are striped
     over ``shard.model_axis`` — this shard's page slabs hold KV/m heads.
     The projections compute the FULL head set (replicated math, so every
     per-head value is bitwise the single-device one), this shard's head
@@ -635,8 +637,7 @@ def moe_block(x, p, cfg: ModelConfig, dist: Dist):
         local = partial(_moe_tp_local, top_k=moe.top_k, capacity=cap,
                         model_axis=ma, batch_axes=batch_axes)
 
-    from repro.launch.mesh import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(None, None), w_spec, w2_spec, w_spec),
         out_specs=(tok_spec, P()),

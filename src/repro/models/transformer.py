@@ -647,7 +647,7 @@ def decode_step_paged_sharded(params, tokens, k_pages, v_pages, block_table,
                               seq_lens, cfg: ModelConfig, mesh, *,
                               use_pallas: bool = False,
                               window_override=None):
-    """``decode_step_paged`` under ``compat_shard_map`` on a (data, model)
+    """``decode_step_paged`` under ``jax.shard_map`` on a (data, model)
     mesh: decode slots data-parallel (tokens / block table / seq_lens
     shard by row; every per-slot op is row-independent, so each data
     shard's math is bitwise the full-batch math), KV heads model-parallel
@@ -664,7 +664,6 @@ def decode_step_paged_sharded(params, tokens, k_pages, v_pages, block_table,
     row-sharded and reassemble to the global (B, 1, V).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import compat_shard_map
     from repro.models.layers import PagedShard
     m = int(mesh.shape.get("model", 1))
     d = int(mesh.shape.get("data", 1))
@@ -680,7 +679,7 @@ def decode_step_paged_sharded(params, tokens, k_pages, v_pages, block_table,
                                  window_override=window_override,
                                  shard=shard)
 
-    f = compat_shard_map(
+    f = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P("data", None), pages_spec, pages_spec,
                   P("data", None), P("data")),

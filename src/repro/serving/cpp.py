@@ -129,8 +129,7 @@ def cpp_prefill(params, tokens, cfg: ModelConfig, mesh: Mesh, *,
             stage_axis)
         return h_last, (k_buf, v_buf)
 
-    from repro.launch.mesh import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         pipeline, mesh=mesh,
         in_specs=(P(), P(stage_axis)),
         out_specs=(P(), P(stage_axis)),

@@ -1227,7 +1227,9 @@ class DecodeWorker:
                 # standalone sizing (per bank): every slot of the bank at
                 # full depth + one extra sequence of staging headroom
                 # (registry runs are evictable on top, so this bound
-                # holds under sharing too)
+                # holds under sharing too). Never sized to fill HBM:
+                # each step and each staging write still copies the
+                # whole slab, so a second copy must fit beside it.
                 per_seq = (max_len + page_tokens - 1) // page_tokens
                 page_pool = DevicePagePool(
                     cfg, n_pages=1 + (max_batch // d + 1) * per_seq,

@@ -182,7 +182,8 @@ def test_model_mamba_block_consistency():
 
 def test_model_prefill_via_pallas_matches_default():
     """End-to-end: the model's prefill with the Pallas flash kernel routed
-    in (REPRO_USE_PALLAS=1, interpret mode) equals the jnp path."""
+    in (REPRO_USE_PALLAS=interpret, the Pallas interpreter) equals the jnp
+    path."""
     import subprocess, sys, os
     code = '''
 import os, sys
@@ -199,10 +200,10 @@ np.save(f"/tmp/pallas_model_{sys.argv[1]}.npy", np.asarray(logits, np.float32))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    for flag in ("0", "1"):
+    for flag in ("0", "interpret"):
         subprocess.run([sys.executable, "-c", code, flag], env=env,
                        check=True, timeout=600)
     a = np.load("/tmp/pallas_model_0.npy")
-    b = np.load("/tmp/pallas_model_1.npy")
+    b = np.load("/tmp/pallas_model_interpret.npy")
     np.testing.assert_allclose(a, b, atol=5e-2, rtol=5e-2)
     assert int(a[0].argmax()) == int(b[0].argmax())

@@ -1,0 +1,85 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode (``test_kernels.py``, ``test_paged_decode.py``) checks the
+kernels' arithmetic but not their tiling: Mosaic refuses block shapes
+whose last two dims are neither (8, 128)-aligned nor the array's own,
+which the interpreter never sees. These tests compile each kernel for a
+described (not attached) ``v5e:2x2`` chip with the TPU compiler that is
+installed alongside jax, and check the program really holds the kernel.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and every pytest
+worker imports every test file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.flash_prefill.kernel import flash_prefill
+from repro.kernels.paged_attention.kernel import (paged_attention,
+                                                  paged_attention_layers)
+
+# (H, KV, D): qwen2.5-3b's grouped heads, and smollm-360m's unpadded ones
+WIDTHS = {"qwen2.5-3b": (16, 2, 128), "smollm-360m": (15, 5, 64)}
+B, PAGE, N_PAGES, MAX_PAGES, L = 8, 64, 289, 32, 36
+SQ, SK = 256, 1024          # one prefill chunk over its 1k-token prefix
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back without one: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, shapes, sharding) -> str:
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_attention_compiles(arch, one_chip):
+    H, KV, D = WIDTHS[arch]
+    text = _compiled_text(paged_attention, [
+        ((B, H, D), jnp.bfloat16),
+        ((N_PAGES, PAGE, KV, D), jnp.bfloat16),
+        ((N_PAGES, PAGE, KV, D), jnp.bfloat16),
+        ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32)], one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_attention_layers_compiles(arch, one_chip):
+    H, KV, D = WIDTHS[arch]
+    text = _compiled_text(paged_attention_layers, [
+        ((L, B, H, D), jnp.bfloat16),
+        ((L, N_PAGES, PAGE, KV, D), jnp.bfloat16),
+        ((L, N_PAGES, PAGE, KV, D), jnp.bfloat16),
+        ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32)], one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_flash_prefill_compiles(arch, one_chip):
+    H, KV, D = WIDTHS[arch]
+    fn = jax.jit(lambda q, k, v: flash_prefill(q, k, v, q_offset=SK - SQ))
+    text = _compiled_text(fn, [
+        ((1, SQ, H, D), jnp.bfloat16),
+        ((1, SK, KV, D), jnp.bfloat16),
+        ((1, SK, KV, D), jnp.bfloat16)], one_chip)
+    assert "tpu_custom_call" in text
