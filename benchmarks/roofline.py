@@ -23,6 +23,7 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 
 from benchmarks.common import RESULTS_DIR, emit
 
@@ -41,7 +42,9 @@ TOKENS = {"train_4k": 4096 * 256, "prefill_32k": 32768 * 32,
 def run_one(arch: str, shape: str, multi_pod: bool = False,
             timeout: int = 3600) -> dict:
     """Dry-run one combo in a fresh 512-device subprocess."""
-    out = f"/tmp/roofline_{arch}_{shape}{'_mp' if multi_pod else ''}.json"
+    out = os.path.join(
+        tempfile.gettempdir(),
+        f"roofline_{arch}_{shape}{'_mp' if multi_pod else ''}.json")
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)   # dryrun sets its own

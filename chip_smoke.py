@@ -29,7 +29,6 @@ a seed.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -56,7 +55,6 @@ TRACE_SEED = 5      # its first 8 requests share a 3-block system prompt
 N_REQUESTS = 8
 MAX_NEW = 16
 MAX_BATCH = 8
-MESH_LAYERS = 4     # --mesh: depth cut (weights replicate on every chip)
 
 # Kernel vs oracle, as max |err| over max |oracle|. The outputs are bf16
 # (8 significant bits: 2^-8 ≈ 0.4% of a value), and the kernel keeps its
@@ -180,6 +178,17 @@ def _serve(params, cfg, requests, *, mesh=None,
             t0 = time.perf_counter()
             pws, dw = build_engine(params, cfg, pool, max_batch=MAX_BATCH,
                                    substrate=substrate, mesh=mesh)
+            logits = set()
+            if mesh is not None:
+                # record where every step the served worker runs leaves
+                # its logits (sharding, global shape)
+                step = dw._step_paged
+
+                def recording_step(*args):
+                    out = step(*args)
+                    logits.add((out[0].sharding, out[0].shape))
+                    return out
+                dw._step_paged = recording_step
             stats, outputs = serve_requests(pws, dw, requests,
                                             admission="baseline",
                                             max_queue=64)
@@ -196,7 +205,7 @@ def _serve(params, cfg, requests, *, mesh=None,
                 f"{short}")
         run = dict(tokens=tokens, wall=wall, compiles=cc, stats=stats,
                    reused=sum(pw.stats()["reused_blocks"] for pw in pws),
-                   page_pool=dw.page_pool)
+                   page_pool=dw.page_pool, logits=logits)
         if dw.page_pool is not None:
             pp = dw.page_pool
             pp.check_leaks()
@@ -257,39 +266,45 @@ def mesh_phase(cfg, data: int, model: int, *, n_requests: int = N_REQUESTS,
     unsharded 1×1 path, on the same requests: tokens bit-exact, and the
     page slabs and logits really spread over ``data*model`` devices."""
     from repro.launch.mesh import make_decode_mesh
-    from repro.models.transformer import decode_step_paged_sharded
     params = init_params(cfg, jax.random.PRNGKey(SEED))
     requests = trace_requests(cfg, n_requests, seed=TRACE_SEED,
                               max_new=max_new, align=BLOCK_TOKENS)
     flat = _serve(params, cfg, requests)
+    del flat["page_pool"]       # its slabs are not needed on device 0 now
     mesh = make_decode_mesh(data, model)
     sharded = _serve(params, cfg, requests, mesh=mesh)
     pp = sharded["page_pool"]
     slab_devs = {sh.device for sh in pp.k_pages.addressable_shards}
     L, n_pages, page, KV, Dh = pp.k_pages.shape
     slab_shards = {sh.data.shape for sh in pp.k_pages.addressable_shards}
-    tbl = jnp.zeros((MAX_BATCH, 1), jnp.int32)
-    logits, _, _ = jax.jit(
-        lambda p, t, kp, vp, tb, ln: decode_step_paged_sharded(
-            p, t, kp, vp, tb, ln, cfg, mesh))(
-        params, jnp.zeros((MAX_BATCH, 1), jnp.int32), pp.k_pages,
-        pp.v_pages, tbl, jnp.zeros((MAX_BATCH,), jnp.int32))
-    logit_devs = {sh.device for sh in logits.addressable_shards}
-    logit_shards = {sh.data.shape for sh in logits.addressable_shards}
+    # the served steps' logits: one placement, each device its data shard's
+    # rows
+    if len(sharded["logits"]) != 1:
+        raise AssertionError(f"served steps left their logits in "
+                             f"{len(sharded['logits'])} placements")
+    (logit_sharding, logit_shape), = sharded["logits"]
+    logit_devs = logit_sharding.device_set
+    logit_shards = {logit_sharding.shard_shape(logit_shape)}
     n_eq, n_tok = _matching(flat["tokens"], sharded["tokens"])
     need = data * model
     print(f"mesh: {data}x{model} over {sorted(d.id for d in mesh.devices.flat)}"
-          f" ({cfg.name} widths, depth cut to {cfg.n_layers} layers): "
+          f" ({cfg.name} widths, {cfg.n_layers} layers): "
           f"k_pages {pp.k_pages.shape} on {len(slab_devs)} devices as "
-          f"{pp.k_pages.sharding.spec}, logits on {len(logit_devs)} devices "
-          f"as {logits.sharding.spec}; tokens {n_eq}/{n_tok} equal to 1x1")
+          f"{pp.k_pages.sharding.spec}, served-step logits on "
+          f"{len(logit_devs)} devices as {logit_sharding.spec}; tokens "
+          f"{n_eq}/{n_tok} equal to 1x1")
     for name, run in (("1x1", flat), (f"{data}x{model}", sharded)):
         print(f"mesh: cold smoke run, not a benchmark: {name} "
               f"{run['wall']:.1f} s wall, {run['compiles']}")
+    mem = [d.memory_stats() or {} for d in mesh.devices.flat]
+    if all("peak_bytes_in_use" in m for m in mem):
+        print("mesh: device peak memory " + ", ".join(
+            f"{m['peak_bytes_in_use'] / 2**30:.2f}" for m in mem) +
+            f" GiB of {mem[0].get('bytes_limit', 0) / 2**30:.2f} GiB")
     # each device: its data bank's pages and its model stripe of KV heads;
     # its data shard's rows of the logits
     want_slab = {(L, n_pages // data, page, KV // model, Dh)}
-    want_logits = {(MAX_BATCH // data,) + logits.shape[1:]}
+    want_logits = {(MAX_BATCH // data,) + logit_shape[1:]}
     if len(slab_devs) != need or len(logit_devs) != need \
             or slab_shards != want_slab or logit_shards != want_logits:
         raise AssertionError(
@@ -316,8 +331,7 @@ def main(argv=None) -> int:
     if args.mesh:
         from repro.launch.mesh import parse_mesh_arg
         data, model = parse_mesh_arg(args.mesh)
-        mesh_phase(dataclasses.replace(cfg, n_layers=MESH_LAYERS),
-                   data, model)
+        mesh_phase(cfg, data, model)
     else:
         kernel_phase(cfg)
         serve_phase(cfg)

@@ -3,18 +3,20 @@
 The serving hot spot of §3 step 2 (incremental prefill): a query chunk of
 ``Sq`` tokens starting at absolute offset ``q_offset`` attends a full
 ``Sk``-token K/V (cached prefix + itself). Online-softmax accumulation
-over K blocks; GQA resolved in the BlockSpec index map (a q-head's grid
-step fetches its kv-head's block — no materialised head expansion).
+over K blocks; GQA resolved in the kernel's head loop (each KV head's
+K/V tile serves its ``group`` query heads — no materialised head
+expansion).
 
-Tiling: grid (B, H, nq, nk) with the K loop as the innermost sequential
+Tiling: grid (B, nq, nk) with the K loop as the innermost sequential
 dimension; VMEM scratch (acc, m, l) persists across the nk steps of one
-(b, h, iq) tile. The kernel runs head-major — (B, H, S, D), transposed
-in the wrapper — so every block's trailing two dims are (rows, D): Mosaic
-tiles those by (8, 128) or takes them whole, and a size-1 head block in
-front of D (the token-major layout) is refused at any real width. Block sizes default to the MXU-native 128×128; the
-working set per step is q(BQ·D) + k,v(2·BK·D) + acc(BQ·D fp32) ≈ 160 KiB
-at D=128 — comfortably inside the ~16 MiB VMEM budget, leaving room for
-double buffering.
+(b, iq) tile. One grid step takes a (BQ, H, D) query tile and (BK, KV, D)
+K/V tiles — every head at once — and loops over the heads statically, as
+the paged decode kernel does: Mosaic tiles the last two block dimensions
+by (8, 128) unless they equal the array's, so the blocks span the full
+(heads, D) trailing axes and no layout change is needed around the call.
+Block sizes default to the MXU-native 128×128; the working set per step at
+H = 16, D = 128 is q(BQ·H·D) + k,v(2·BK·KV·D) + acc(H·BQ·D fp32) ≈ 2 MiB,
+inside the ~16 MiB VMEM budget with room for double buffering.
 """
 from __future__ import annotations
 
@@ -33,19 +35,16 @@ NEG_INF = -1e30
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, q_offset: int, window: int,
-                  bq: int, bk: int, nk: int):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+                  bq: int, bk: int, nk: int, kv_heads: int, group: int):
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
+    full = slice(None)
 
     @pl.when(ik == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)              # (BQ, D)
-    k = k_ref[0, 0].astype(jnp.float32)              # (BK, D)
-    v = v_ref[0, 0].astype(jnp.float32)
 
     qpos = q_offset + iq * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, bk), 0)
@@ -54,25 +53,32 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     if window:
         mask &= (qpos - kpos) < window
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask, s, NEG_INF)
+    for g in range(kv_heads):
+        k = k_ref[0, full, g, full].astype(jnp.float32)      # (BK, D)
+        v = v_ref[0, full, g, full].astype(jnp.float32)
+        for h in range(g * group, (g + 1) * group):
+            q = q_ref[0, full, h, full].astype(jnp.float32)  # (BQ, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[:, 0]                              # (BQ,)
-    m_cur = jnp.maximum(m_prev, s.max(axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    p = jnp.where(mask, p, 0.0)
-
-    l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_cur
+            m_prev = m_ref[h]                                # (BQ, 1)
+            m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_cur
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        den = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / den[:, None]).astype(o_ref.dtype)
+        d = acc_ref.shape[-1]
+        for h in range(kv_heads * group):
+            den = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, full, h * d:(h + 1) * d] = (
+                acc_ref[h] / den).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("q_offset", "window", "bq",
@@ -86,28 +92,30 @@ def flash_prefill(q, k, v, *, q_offset: int = 0, window: int = 0,
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
     group = H // KV
     nq, nk = Sq // bq, Sk // bk
-    grid = (B, H, nq, nk)
 
     kernel = functools.partial(
         _flash_kernel, scale=1.0 / (D ** 0.5), q_offset=q_offset,
-        window=window, bq=bq, bk=bk, nk=nk)
+        window=window, bq=bq, bk=bk, nk=nk, kv_heads=KV, group=group)
 
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, iq, ik, g=group: (b, h // g, ik, 0))
+    q_spec = pl.BlockSpec((1, bq, H, D), lambda b, iq, ik: (b, iq, 0, 0))
+    kv_spec = pl.BlockSpec((1, bk, KV, D), lambda b, iq, ik: (b, ik, 0, 0))
+    # the output is written head by head into lane slices of (Sq, H·D): a
+    # free reshape of (Sq, H, D), where Mosaic cannot store one head's
+    # (BQ, D) tile across the head axis at D < 128
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, H * D),
+                               lambda b, iq, ik: (b, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),   # acc
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max m
-            pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
+            pltpu.VMEM((H, bq, D), jnp.float32),   # acc
+            pltpu.VMEM((H, bq, 1), jnp.float32),   # running max m
+            pltpu.VMEM((H, bq, 1), jnp.float32),   # running sum l
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
-    return out.transpose(0, 2, 1, 3)
+    )(q, k, v)
+    return out.reshape(q.shape)

@@ -25,34 +25,37 @@ import sys
 import threading
 import time
 
+MAX_LEN = 2048          # decode slot length: prompt + new tokens
+PREFILL_CHUNK = 256     # tokens per prefill device chunk
+MAX_INPUT = 1536        # trace prompts are cut to this many tokens
 
-def build_engine(params, cfg, pool, *, max_batch: int, max_len: int = 2048,
+
+def build_engine(params, cfg, pool, *, max_batch: int,
                  substrate: str = "paged", mesh=None,
-                 prefill_workers: int = 1, prefill_chunk: int = 256,
-                 ssd_mode: str = "overlap", width_buckets: int = 1):
+                 prefill_workers: int = 1, ssd_mode: str = "overlap",
+                 width_buckets: int = 1):
     """The serving engine of one instance: ``prefill_workers``
     ``PrefillWorker``s over the host pool ``pool`` and one
     ``DecodeWorker``, sharing one ``DevicePagePool`` on the paged
     substrate (the zero-copy §3 handoff). Returns ``(prefill_workers,
     decode_worker)``; the page pool, sized by the decode worker from
-    ``max_batch`` and ``max_len``, is ``decode_worker.page_pool``."""
+    ``max_batch`` and ``MAX_LEN``, is ``decode_worker.page_pool``."""
     from repro.serving.engine import DecodeWorker, PrefillWorker
-    dw = DecodeWorker(params, cfg, max_batch=max_batch, max_len=max_len,
+    dw = DecodeWorker(params, cfg, max_batch=max_batch, max_len=MAX_LEN,
                       substrate=substrate, mesh=mesh,
                       width_buckets=width_buckets)
-    pws = [PrefillWorker(params, cfg, pool, prefill_chunk=prefill_chunk,
+    pws = [PrefillWorker(params, cfg, pool, prefill_chunk=PREFILL_CHUNK,
                          ssd_mode=ssd_mode, page_pool=dw.page_pool)
            for _ in range(prefill_workers)]
     return pws, dw
 
 
-def trace_requests(cfg, n: int, *, seed: int, max_new: int,
-                   max_input: int = 1536, align: int = 1,
+def trace_requests(cfg, n: int, *, seed: int, max_new: int, align: int = 1,
                    trace_path=None) -> list:
     """``n`` ``ServingRequest``s from a Mooncake-format trace (loaded, or
     generated from ``seed``), realised to tokens whose 512-token blocks
     follow the trace's hash chains, so prefix sharing is the trace's.
-    Prompts are cut to ``max_input`` tokens and, with ``align`` > 1,
+    Prompts are cut to ``MAX_INPUT`` tokens and, with ``align`` > 1,
     rounded up to a multiple of it (fewer distinct prefill shapes, so
     fewer compiles)."""
     from repro.core.trace import (BLOCK_TOKENS, TraceSpec, generate_trace,
@@ -68,7 +71,7 @@ def trace_requests(cfg, n: int, *, seed: int, max_new: int,
         reqs = generate_trace(spec)[:n]
     out = []
     for r in reqs:
-        length = min(-(-r.input_length // align) * align, max_input)
+        length = min(-(-r.input_length // align) * align, MAX_INPUT)
         r.input_length = length
         r.hash_ids = r.hash_ids[:max(length // BLOCK_TOKENS, 1)]
         out.append(ServingRequest(

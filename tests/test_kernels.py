@@ -180,7 +180,7 @@ def test_model_mamba_block_consistency():
                                atol=5e-3, rtol=5e-3)
 
 
-def test_model_prefill_via_pallas_matches_default():
+def test_model_prefill_via_pallas_matches_default(tmp_path):
     """End-to-end: the model's prefill with the Pallas flash kernel routed
     in (REPRO_USE_PALLAS=interpret, the Pallas interpreter) equals the jnp
     path."""
@@ -195,15 +195,17 @@ cfg = get_config("qwen2.5-3b").reduced()
 params = init_params(cfg, jax.random.PRNGKey(0))
 tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0, cfg.vocab_size)
 logits, _ = jax.jit(lambda p, t: prefill(p, t, cfg))(params, tokens)
-np.save(f"/tmp/pallas_model_{sys.argv[1]}.npy", np.asarray(logits, np.float32))
+np.save(sys.argv[2], np.asarray(logits, np.float32))
 '''
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    for flag in ("0", "interpret"):
-        subprocess.run([sys.executable, "-c", code, flag], env=env,
+    out = {flag: str(tmp_path / f"pallas_model_{flag}.npy")
+           for flag in ("0", "interpret")}
+    for flag, path in out.items():
+        subprocess.run([sys.executable, "-c", code, flag, path], env=env,
                        check=True, timeout=600)
-    a = np.load("/tmp/pallas_model_0.npy")
-    b = np.load("/tmp/pallas_model_interpret.npy")
+    a = np.load(out["0"])
+    b = np.load(out["interpret"])
     np.testing.assert_allclose(a, b, atol=5e-2, rtol=5e-2)
     assert int(a[0].argmax()) == int(b[0].argmax())

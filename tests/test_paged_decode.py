@@ -99,6 +99,31 @@ def test_batched_over_layers_entry_matches_per_layer():
                                atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("entry", ["per_layer", "layers"])
+@pytest.mark.parametrize("case", ["qh2kv", "window", "indivisible"])
+def test_pallas_refuses_what_it_cannot_take(entry, case):
+    """use_pallas=True on a padded head map, a window, or heads that do
+    not divide raises; it never quietly returns the oracle instead."""
+    from repro.kernels.paged_attention.ops import (
+        paged_decode_attention, paged_decode_attention_layers)
+    B, H, KV, D, P, page, mp = 2, 6 if case == "indivisible" else 4, 4, \
+        32, 8, 16, 2
+    q, kp, vp = _rand_paged(B, H, KV, D, P, page, mp)
+    kw = dict(use_pallas=True, interpret=True)
+    if case == "qh2kv":
+        kw["qh2kv"] = jnp.asarray([0, 1, 2, 3], jnp.int32)
+    elif case == "window":
+        kw["window"] = 8
+    table = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
+    lens = jnp.asarray([20, 9], jnp.int32)
+    with pytest.raises(ValueError, match="grouped GQA"):
+        if entry == "per_layer":
+            paged_decode_attention(q, kp, vp, table, lens, **kw)
+        else:
+            paged_decode_attention_layers(q[None], kp[None], vp[None],
+                                          table, lens, **kw)
+
+
 def test_ref_qh2kv_matches_manual_expansion():
     """The padded-GQA oracle (explicit query→kv map) equals attention over
     manually expanded pages."""
