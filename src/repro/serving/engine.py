@@ -32,6 +32,7 @@ from repro.models.transformer import (Caches, KVCache, decode_step,
                                       decode_step_paged_sharded, init_caches,
                                       paged_shard_reason, prefill)
 from repro.serving.request import ServingRequest
+from repro.serving.tracing import span
 from repro.serving.transport import InProcPeer, PeerError, fallback_reason
 
 
@@ -752,7 +753,10 @@ class ChunkedPrefill:
         self.prompt_len = len(self.tokens)
         self.chunks_done = 0
         self.result: Optional[PrefillResult] = None
-        self._gen = worker._chunks(self.tokens, session)
+        # arguments every profiler span of this prefill carries (the
+        # serving loop sets ``req``)
+        self.info: dict = {}
+        self._gen = worker._chunks(self.tokens, session, self.info)
 
     @property
     def done(self) -> bool:
@@ -762,14 +766,15 @@ class ChunkedPrefill:
         """Run one device chunk; True once the prefill finished."""
         if self.result is not None:
             return True
-        try:
-            next(self._gen)
-            self.chunks_done += 1
-            return False
-        except StopIteration as e:
-            self.chunks_done += 1
-            self.result = e.value
-            return True
+        with span("prefill.chunk", **self.info):
+            try:
+                next(self._gen)
+                self.chunks_done += 1
+                return False
+            except StopIteration as e:
+                self.chunks_done += 1
+                self.result = e.value
+                return True
 
     def drain(self) -> PrefillResult:
         while not self.advance():
@@ -814,8 +819,8 @@ class PrefillWorker:
         self.counters = dict(reused_blocks=0, computed_tokens=0, requests=0,
                              ssd_loaded_blocks=0, overlapped_requests=0,
                              fallback_blocks=0, peer_blocks=0,
-                             skipped_blocks=0, page_oom=0, chunks=0,
-                             stage_deferred=0)
+                             skipped_blocks=0, chunks=0, h2d_bytes=0,
+                             d2h_bytes=0, put_bytes=0, stage_bytes=0)
         # serving-loop hook: called with the page count a stage would pin;
         # returning False skips staging (the join stages later) so staged
         # results can't eat the decode batch's reserved growth pages
@@ -837,37 +842,65 @@ class PrefillWorker:
             return 0.0
         return self._t_block_ema * self.chunk / BLOCK_TOKENS
 
-    def _chunk_extend(self, t, caches, lo: int, hi: int):
+    def _chunk_extend(self, t, caches, lo: int, hi: int, info: dict):
         """One timed device chunk (the resumable unit)."""
-        t0 = time.monotonic()
-        logits, caches = self._extend(self.params, t[:, lo:hi], caches)
-        jax.block_until_ready(logits)
-        self._note_compute(hi - lo, time.monotonic() - t0)
+        with span("prefill.compute", lo=lo, hi=hi, **info):
+            t0 = time.monotonic()
+            logits, caches = self._extend(self.params, t[:, lo:hi], caches)
+            jax.block_until_ready(logits)
+            self._note_compute(hi - lo, time.monotonic() - t0)
         self.counters["chunks"] += 1
         return logits, caches
 
+    def _upload(self, kv: KVCache, lo: int, hi: int, hash_ids) -> KVCache:
+        """Host-pool blocks ``hash_ids`` into the dense cache's tokens
+        [lo, hi)."""
+        k_np, v_np = self.pool.get(hash_ids)
+        self.counters["h2d_bytes"] += k_np.nbytes + v_np.nbytes
+        return KVCache(k=kv.k.at[:, 0, lo:hi].set(jnp.asarray(k_np)),
+                       v=kv.v.at[:, 0, lo:hi].set(jnp.asarray(v_np)))
+
+    def _readback(self, logits, caches, info: dict):
+        """The first token and the whole dense cache, device to host."""
+        with span("prefill.readback", **info):
+            first = int(jnp.argmax(logits[0, -1]))
+            k_full = np.asarray(caches.kv.k[:, 0])
+            v_full = np.asarray(caches.kv.v[:, 0])
+        self.counters["d2h_bytes"] += k_full.nbytes + v_full.nbytes
+        return first, k_full, v_full
+
+    def _put(self, hash_ids, k_full, v_full, i: int, j: int) -> None:
+        """Blocks [i, j) of the dense cache into the host pool."""
+        sl = slice(i * BLOCK_TOKENS, j * BLOCK_TOKENS)
+        k, v = k_full[:, sl], v_full[:, sl]
+        self.pool.put(hash_ids[i:j], k, v, start_pos=i)
+        self.counters["put_bytes"] += k.nbytes + v.nbytes
+
     def _stage(self, hash_ids, k_full, v_full, S) -> Optional[list[int]]:
-        if self.page_pool is not None and self.stage_guard is not None \
-                and not self.stage_guard(self.page_pool.pages_for(S)):
-            self.counters["stage_deferred"] += 1
+        pp = self.page_pool
+        if pp is None or (self.stage_guard is not None
+                          and not self.stage_guard(pp.pages_for(S))):
             return None
-        pages = stage_run(self.page_pool, hash_ids, k_full, v_full, S)
-        if pages is None and self.page_pool is not None:
-            self.counters["page_oom"] += 1
+        written = pp.pages_written
+        pages = stage_run(pp, hash_ids, k_full, v_full, S)
+        if pages is not None:
+            self.counters["stage_bytes"] += \
+                (pp.pages_written - written) * pp.page_bytes
         return pages
 
     def stats(self) -> dict:
         """Unified snapshot (cross-component ``stats()`` protocol):
-        lifetime counters + hasher memo effectiveness."""
-        out = dict(self.counters)
-        out["hash_blocks"] = self.hasher.blocks_hashed
-        out["hash_memo_hits"] = self.hasher.memo_hits
-        return out
+        lifetime counters, the KV bytes of each tier transfer among them
+        (``h2d_bytes`` host-pool hits uploaded, ``d2h_bytes`` dense caches
+        read back, ``put_bytes`` stored into the host pool,
+        ``stage_bytes`` written into device pages)."""
+        return dict(self.counters)
 
-    def _stage_result(self, hash_ids, k_full, v_full, S) -> dict:
+    def _stage_result(self, hash_ids, k_full, v_full, S, info: dict) -> dict:
         """PrefillResult kwargs for the staged page run (+ generation
         snapshot so late re-joins can detect recycled pages)."""
-        pages = self._stage(hash_ids, k_full, v_full, S)
+        with span("prefill.stage", **info):
+            pages = self._stage(hash_ids, k_full, v_full, S)
         return dict(
             hash_ids=hash_ids, pages=pages, page_pool=self.page_pool,
             page_gens=None if pages is None
@@ -882,57 +915,61 @@ class PrefillWorker:
                  session=None) -> PrefillResult:
         return self.start(tokens, session=session).drain()
 
-    def _chunks(self, tokens: np.ndarray, session=None):
+    def _chunks(self, tokens: np.ndarray, session=None, info=None):
         """Generator behind ``ChunkedPrefill``: yields between device
-        chunks; its StopIteration value is the ``PrefillResult``."""
+        chunks; its StopIteration value is the ``PrefillResult``.
+        ``info``: arguments of every profiler span it opens."""
         cfg = self.cfg
         assert cfg.attention_layers == cfg.n_layers, \
             "PrefillWorker KV path supports uniform attention stacks"
+        info = {} if info is None else info
         S = len(tokens)
-        hash_ids = self.hasher.hash_ids(tokens, session=session)
+        with span("prefill.lookup", **info):
+            hash_ids = self.hasher.hash_ids(tokens, session=session)
+            plan = None
+            if self.ssd_mode == "overlap" and \
+                    self.pool.prefetcher is not None:
+                plan = self.pool.plan_fetch(hash_ids)
+                n_res = plan.n_resident
+                if n_res * BLOCK_TOKENS >= S:    # full hit: keep a tail to
+                    n_res = max((S - 1) // BLOCK_TOKENS, 0)  # recompute
+                plan = plan.truncate(n_res)      # the logits
+                if not (plan.has_ssd or plan.has_remote):
+                    plan = None
+            if plan is None:
+                # blocking path: flat pool, legacy tiered pool, or
+                # synchronous file-backed/peer loads (ssd_mode="blocking")
+                peer0 = self.pool.peer_blocks_fetched
+                n_hit = self.pool.match_prefix(hash_ids)
+        if plan is not None:
+            result = yield from self._chunks_overlapped(
+                tokens, hash_ids, plan, info)
+            return result
 
-        if self.ssd_mode == "overlap" and self.pool.prefetcher is not None:
-            plan = self.pool.plan_fetch(hash_ids)
-            n_res = plan.n_resident
-            if n_res * BLOCK_TOKENS >= S:    # full hit: keep a tail to
-                n_res = max((S - 1) // BLOCK_TOKENS, 0)  # recompute logits
-            plan = plan.truncate(n_res)
-            if plan.has_ssd or plan.has_remote:
-                result = yield from self._chunks_overlapped(
-                    tokens, hash_ids, plan)
-                return result
-
-        # blocking path: flat pool, legacy tiered pool, or synchronous
-        # file-backed/peer loads (ssd_mode="blocking")
-        peer0 = self.pool.peer_blocks_fetched
-        n_hit = self.pool.match_prefix(hash_ids)
         prefix_tokens = n_hit * BLOCK_TOKENS
         if prefix_tokens >= S:           # full hit: recompute last block's
             n_hit = max((S - 1) // BLOCK_TOKENS, 0)  # tail to get logits
             prefix_tokens = n_hit * BLOCK_TOKENS
 
-        t = jnp.asarray(tokens[None, :], jnp.int32)
-        caches = init_caches(cfg, 1, S)
-        caches = caches._replace(length=jnp.asarray(0, jnp.int32))
-        if n_hit:
-            k_np, v_np = self.pool.get(hash_ids[:n_hit])
-            kv = KVCache(
-                k=caches.kv.k.at[:, 0, :prefix_tokens].set(jnp.asarray(k_np)),
-                v=caches.kv.v.at[:, 0, :prefix_tokens].set(jnp.asarray(v_np)))
-            caches = caches._replace(kv=kv,
-                                     length=jnp.asarray(prefix_tokens, jnp.int32))
+        with span("prefill.load", blocks=n_hit, **info):
+            t = jnp.asarray(tokens[None, :], jnp.int32)
+            caches = init_caches(cfg, 1, S)
+            caches = caches._replace(length=jnp.asarray(0, jnp.int32))
+            if n_hit:
+                kv = self._upload(caches.kv, 0, prefix_tokens,
+                                  hash_ids[:n_hit])
+                caches = caches._replace(
+                    kv=kv, length=jnp.asarray(prefix_tokens, jnp.int32))
         # chunked incremental prefill over the uncached suffix (a cold
         # request is just the n_hit=0 case: extending an empty cache chunk
         # by chunk is bit-identical to a monolithic prefill)
         logits = None
         for lo in range(prefix_tokens, S, self.chunk):
             hi = min(lo + self.chunk, S)
-            logits, caches = self._chunk_extend(t, caches, lo, hi)
+            logits, caches = self._chunk_extend(t, caches, lo, hi, info)
             if hi < S:
                 yield               # suspension point for the serving loop
-        first = int(jnp.argmax(logits[0, -1]))
-        k_full = np.asarray(caches.kv.k[:, 0])
-        v_full = np.asarray(caches.kv.v[:, 0])
+        first, k_full, v_full = self._readback(logits, caches, info)
 
         # layer-wise store-back of every fresh full block (§5.2: on TPU the
         # per-layer store launches as soon as that layer's KV exists; here
@@ -940,9 +977,8 @@ class PrefillWorker:
         # per-layer stack)
         n_total = len(hash_ids)
         if n_total > n_hit:
-            sl = slice(n_hit * BLOCK_TOKENS, n_total * BLOCK_TOKENS)
-            self.pool.put(hash_ids[n_hit:], k_full[:, sl], v_full[:, sl],
-                          start_pos=n_hit)
+            with span("prefill.store", **info):
+                self._put(hash_ids, k_full, v_full, n_hit, n_total)
         n_peer = self.pool.peer_blocks_fetched - peer0
         self.counters["reused_blocks"] += n_hit
         self.counters["computed_tokens"] += S - prefix_tokens
@@ -951,10 +987,11 @@ class PrefillWorker:
         return PrefillResult(first_token=first, kv_k=k_full, kv_v=v_full,
                              prompt_len=S, reused_blocks=n_hit,
                              new_blocks=n_total - n_hit, peer_blocks=n_peer,
-                             **self._stage_result(hash_ids, k_full, v_full, S))
+                             **self._stage_result(hash_ids, k_full, v_full, S,
+                                                  info))
 
     def _chunks_overlapped(self, tokens: np.ndarray, hash_ids: list[int],
-                           plan: FetchPlan):
+                           plan: FetchPlan, info: dict):
         """Head recompute ∥ tail SSD load (§5.2 / Jin et al., executable),
         as a chunk-resumable generator.
 
@@ -969,30 +1006,28 @@ class PrefillWorker:
         S = len(tokens)
         n = plan.n_resident
         peer0 = self.pool.peer_blocks_fetched
-        tl = self.pool.est_block_read_s()
-        tc = self._t_block_ema if self._t_block_ema is not None else tl
-        # peer blocks are loads for the split search (the local read EMA
-        # is the available per-block load estimate; the network hop of an
-        # in-process peer is free, so it errs mildly toward recompute)
-        ov = overlap_split(["dram" if t == "dram" else "ssd"
-                            for t in plan.tiers], tc, tl)
-        s, d0 = ov.split, ov.dram_head
-        handle = self.pool.start_prefetch(plan, from_block=s)
-        if d0:
-            self.pool.meta.touch_keys(hash_ids[:d0])
+        with span("prefill.lookup", **info):
+            tl = self.pool.est_block_read_s()
+            tc = self._t_block_ema if self._t_block_ema is not None else tl
+            # peer blocks are loads for the split search (the local read
+            # EMA is the available per-block load estimate; the network
+            # hop of an in-process peer is free, so it errs mildly toward
+            # recompute)
+            ov = overlap_split(["dram" if t == "dram" else "ssd"
+                                for t in plan.tiers], tc, tl)
+            s, d0 = ov.split, ov.dram_head
+            handle = self.pool.start_prefetch(plan, from_block=s)
+            if d0:
+                self.pool.meta.touch_keys(hash_ids[:d0])
 
-        t = jnp.asarray(tokens[None, :], jnp.int32)
-        caches = init_caches(cfg, 1, S)
-        caches = caches._replace(length=jnp.asarray(0, jnp.int32))
-        pos = 0
-        if d0:
-            k_np, v_np = self.pool.get(hash_ids[:d0])
-            kv = KVCache(
-                k=caches.kv.k.at[:, 0, :d0 * B].set(jnp.asarray(k_np)),
-                v=caches.kv.v.at[:, 0, :d0 * B].set(jnp.asarray(v_np)))
-            caches = caches._replace(kv=kv,
-                                     length=jnp.asarray(d0 * B, jnp.int32))
-            pos = d0 * B
+        with span("prefill.load", blocks=d0, **info):
+            t = jnp.asarray(tokens[None, :], jnp.int32)
+            caches = init_caches(cfg, 1, S)
+            caches = caches._replace(length=jnp.asarray(0, jnp.int32))
+            if d0:
+                kv = self._upload(caches.kv, 0, d0 * B, hash_ids[:d0])
+                caches = caches._replace(
+                    kv=kv, length=jnp.asarray(d0 * B, jnp.int32))
 
         # head assembly, overlapping the prefetch thread's layer loads:
         # DRAM blocks interleaved inside [d0, s) are chunk-SKIPPED — their
@@ -1008,36 +1043,32 @@ class PrefillWorker:
                 j = i
                 while j < s and plan.tiers[j] == "dram":
                     j += 1
-                k_np, v_np = self.pool.get(hash_ids[i:j])
-                self.pool.meta.touch_keys(hash_ids[i:j])
-                kv = caches.kv
-                kv = KVCache(
-                    k=kv.k.at[:, 0, i * B:j * B].set(jnp.asarray(k_np)),
-                    v=kv.v.at[:, 0, i * B:j * B].set(jnp.asarray(v_np)))
-                caches = caches._replace(
-                    kv=kv, length=jnp.asarray(j * B, jnp.int32))
+                with span("prefill.load", blocks=j - i, **info):
+                    kv = self._upload(caches.kv, i * B, j * B,
+                                      hash_ids[i:j])
+                    self.pool.meta.touch_keys(hash_ids[i:j])
+                    caches = caches._replace(
+                        kv=kv, length=jnp.asarray(j * B, jnp.int32))
             else:
                 j = i
                 while j < s and plan.tiers[j] != "dram":
                     j += 1
                 for lo in range(i * B, j * B, self.chunk):
                     hi = min(lo + self.chunk, j * B)
-                    _, caches = self._chunk_extend(t, caches, lo, hi)
+                    _, caches = self._chunk_extend(t, caches, lo, hi, info)
                     yield
             i = j
         n_skip = ov.head_skipped
 
         # §5.2 barrier: verify + install the loaded tail
-        n_tail = self.pool.finish_fetch(plan, handle, from_block=s)
-        usable = s + n_tail
-        if n_tail:
-            k_np, v_np = self.pool.get(hash_ids[s:usable])
-            kv = caches.kv
-            kv = KVCache(
-                k=kv.k.at[:, 0, s * B:usable * B].set(jnp.asarray(k_np)),
-                v=kv.v.at[:, 0, s * B:usable * B].set(jnp.asarray(v_np)))
-            caches = caches._replace(kv=kv,
-                                     length=jnp.asarray(usable * B, jnp.int32))
+        with span("prefill.load", blocks=n - s, **info):
+            n_tail = self.pool.finish_fetch(plan, handle, from_block=s)
+            usable = s + n_tail
+            if n_tail:
+                kv = self._upload(caches.kv, s * B, usable * B,
+                                  hash_ids[s:usable])
+                caches = caches._replace(
+                    kv=kv, length=jnp.asarray(usable * B, jnp.int32))
 
         # uncached suffix (+ any blocks lost to verification failures).
         # Always non-empty — the caller truncates full-hit plans so that
@@ -1047,32 +1078,27 @@ class PrefillWorker:
         logits = None
         for lo in range(usable * B, S, self.chunk):
             hi = min(lo + self.chunk, S)
-            logits, caches = self._chunk_extend(t, caches, lo, hi)
+            logits, caches = self._chunk_extend(t, caches, lo, hi, info)
             if hi < S:
                 yield
-        first = int(jnp.argmax(logits[0, -1]))
-        k_full = np.asarray(caches.kv.k[:, 0])
-        v_full = np.asarray(caches.kv.v[:, 0])
+        first, k_full, v_full = self._readback(logits, caches, info)
 
         # store-back: the RECOMPUTED head runs (chunk-skipped DRAM blocks
         # are already pool-resident) and the fresh suffix blocks
         n_total = len(hash_ids)
-        i = d0
-        while i < s:
-            if plan.tiers[i] == "dram":
-                i += 1
-                continue
-            j = i
-            while j < s and plan.tiers[j] != "dram":
-                j += 1
-            sl = slice(i * B, j * B)
-            self.pool.put(hash_ids[i:j], k_full[:, sl], v_full[:, sl],
-                          start_pos=i)
-            i = j
-        if n_total > usable:
-            sl = slice(usable * B, n_total * B)
-            self.pool.put(hash_ids[usable:n_total], k_full[:, sl],
-                          v_full[:, sl], start_pos=usable)
+        with span("prefill.store", **info):
+            i = d0
+            while i < s:
+                if plan.tiers[i] == "dram":
+                    i += 1
+                    continue
+                j = i
+                while j < s and plan.tiers[j] != "dram":
+                    j += 1
+                self._put(hash_ids, k_full, v_full, i, j)
+                i = j
+            if n_total > usable:
+                self._put(hash_ids, k_full, v_full, usable, n_total)
 
         reused = d0 + n_skip + n_tail
         n_peer = self.pool.peer_blocks_fetched - peer0
@@ -1089,7 +1115,8 @@ class PrefillWorker:
                              new_blocks=len(hash_ids) - reused,
                              ssd_blocks=n_tail, peer_blocks=n_peer,
                              overlapped=True, skipped_blocks=n_skip,
-                             **self._stage_result(hash_ids, k_full, v_full, S))
+                             **self._stage_result(hash_ids, k_full, v_full, S,
+                                                  info))
 
 
 @dataclass
@@ -1592,23 +1619,35 @@ class DecodeWorker:
     def step(self) -> list[tuple[int, int, bool]]:
         """One continuous-batching iteration.
         Returns [(req_id, token, finished)] for active slots."""
-        if self.n_active == 0:
+        n_active = self.n_active
+        if n_active == 0:
             return []
         self.counters["steps"] += 1
-        if self.substrate == "paged":
-            active = [i for i, s in enumerate(self.slots) if s is not None]
-            self._prepare_writes(active)
-            if self.width_buckets > 1:
-                nxt = self._step_bucketed(active)
+        with span("decode.step", slots=n_active):
+            if self.substrate == "paged":
+                active = [i for i, s in enumerate(self.slots)
+                          if s is not None]
+                with span("decode.prepare"):
+                    self._prepare_writes(active)
+                with span("decode.launch"):
+                    if self.width_buckets > 1:
+                        nxt = self._step_bucketed(active)
+                    else:
+                        nxt = self._step_full(active)
+                    for i in active:
+                        self.seq_lens[i] += 1
+                    self.tokens = nxt[:, None]
             else:
-                nxt = self._step_full(active)
-            for i in active:
-                self.seq_lens[i] += 1
-        else:
-            logits, self.caches = self._step(self.params, self.tokens,
-                                             self.caches)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        self.tokens = nxt[:, None]
+                with span("decode.launch"):
+                    logits, self.caches = self._step(self.params,
+                                                     self.tokens, self.caches)
+                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    self.tokens = nxt[:, None]
+            with span("decode.readout"):
+                return self._readout(nxt)
+
+    def _readout(self, nxt: jax.Array) -> list[tuple[int, int, bool]]:
+        """Each active slot's next token; finished slots are freed."""
         out = []
         for i, s in enumerate(self.slots):
             if s is None:

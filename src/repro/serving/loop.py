@@ -64,6 +64,7 @@ from repro.serving.engine import (ChunkedPrefill, DecodeWorker,
                                   PrefillResult, PrefillWorker, plan_restore,
                                   stage_run)
 from repro.serving.request import RequestOutput, ServingRequest
+from repro.serving.tracing import span
 
 
 @dataclass
@@ -134,7 +135,9 @@ class ServingLoop:
         self.restore_mode = restore_mode
         self.spill_pool = spill_pool if spill_pool is not None \
             else self.pws[0].pool
-        self._arrivals: "queue.Queue[ServingRequest]" = queue.Queue()
+        # (request, time submitted)
+        self._arrivals: "queue.Queue[tuple[ServingRequest, float]]" = \
+            queue.Queue()
         # guards the client-visible flags/counters that submit() threads
         # and the engine thread both touch
         self._lock = threading.Lock()
@@ -185,6 +188,7 @@ class ServingLoop:
         ``DeprecationWarning``. The queue-cap check and the enqueue are
         atomic under the loop lock (concurrent submitters can not race
         past ``max_queue``)."""
+        t_submit = time.monotonic()
         if not isinstance(request, ServingRequest):
             warnings.warn(
                 "ServingLoop.submit(req_id, tokens, max_new, ...) is "
@@ -204,7 +208,7 @@ class ServingLoop:
                                                     request.priority):
                 self._counters["rejected"] += 1
                 return False
-            self._arrivals.put(request)
+            self._arrivals.put((request, t_submit))
         return True
 
     def close_intake(self) -> None:
@@ -277,22 +281,32 @@ class ServingLoop:
     def _iteration(self) -> None:
         self._iter += 1
         self._bump("iterations")
-        self._drain_arrivals()
-        self._try_joins()
-        t_step = self._decode_step()
-        self._run_chunks(t_step)
+        arrival = self._next_arrival()
+        if arrival is None and self.idle:
+            return          # the loop spins while idle: no work, no span
+        with span("loop.iteration"):
+            if arrival is not None:
+                with span("loop.admit"):
+                    while arrival is not None:
+                        req, t_submit = arrival
+                        self._start_prefill(req, t_submit=t_submit)
+                        arrival = self._next_arrival()
+            if self._pending_join:
+                with span("loop.join"):
+                    self._try_joins()
+            t_step = self._decode_step()
+            self._run_chunks(t_step)
 
-    def _drain_arrivals(self) -> None:
-        while True:
-            try:
-                req = self._arrivals.get_nowait()
-            except queue.Empty:
-                return
-            self._start_prefill(req)
+    def _next_arrival(self) -> Optional[tuple[ServingRequest, float]]:
+        try:
+            return self._arrivals.get_nowait()
+        except queue.Empty:
+            return None
 
     def _start_prefill(self, req: ServingRequest,
                        tokens_override: Optional[np.ndarray] = None,
-                       resume_emitted: Optional[list] = None) -> None:
+                       resume_emitted: Optional[list] = None, *,
+                       t_submit: Optional[float] = None) -> None:
         """Route to the free worker with the deepest pool residency for
         this prompt (Conductor-style cache-aware routing, loop-local);
         every worker busy → round-robin pile-up is fine, generators are
@@ -309,11 +323,12 @@ class ServingLoop:
             if depth > best_depth:
                 best, best_depth = i, depth
         cp = self.pws[best].start(toks, session=req.session)
+        cp.info["req"] = req.req_id
         self._active.append(_Active(req, cp, best, emitted=resume_emitted))
         self._busy.add(best)
         if req.req_id not in self.outputs:
             self.outputs[req.req_id] = RequestOutput(
-                req_id=req.req_id, priority=req.priority)
+                req_id=req.req_id, priority=req.priority, t_submit=t_submit)
 
     # ---- page headroom + preemption ------------------------------------
     def _obtainable_pages(self) -> int:
@@ -543,7 +558,13 @@ class ServingLoop:
             return False
         self._rr %= len(self._active)
         act = self._active[self._rr]
+        out = self.outputs[act.request.req_id]
+        t0 = time.monotonic()
+        if out.t_first_chunk is None:
+            out.t_first_chunk = t0
         done = act.cp.advance()
+        if act.emitted is None:         # not a recompute restore's replay
+            out.prefill_busy_s += time.monotonic() - t0
         self._bump("prefill_chunks")
         if done:
             self._active.pop(self._rr)

@@ -323,6 +323,17 @@ class DevicePagePool:
         return (n_tokens + self.page_tokens - 1) // self.page_tokens
 
     @property
+    def page_bytes(self) -> int:
+        """Bytes of one page's k and v together, over all layers."""
+        return 2 * self.k_pages.nbytes // self.k_pages.shape[1]
+
+    @property
+    def pages_written(self) -> int:
+        """Pages written by ``write_run`` over the pool's life."""
+        with self._lock:
+            return self.counters["pages_written"]
+
+    @property
     def used_pages(self) -> int:
         with self._lock:
             return int((self.refs > 0).sum())

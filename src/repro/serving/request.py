@@ -58,6 +58,14 @@ class RequestOutput:
     back from spilled bytes — or ``"recompute"`` — re-prefilled);
     ``completed_iter`` is the loop iteration the final token landed on
     (deterministic in ``iterate()``-driven mode, the benchmarks' clock).
+
+    The request's own timeline, on ``token_t``'s clock: ``t_submit``
+    (``ServingLoop.submit`` called), ``t_first_chunk`` (its first
+    prefill chunk starts) and ``prefill_busy_s`` (wall seconds of its own
+    prefill chunks; a recompute restore's replay is not counted). So
+    ``t_first_chunk - t_submit`` is its time queued, and
+    ``token_t[0] - t_first_chunk - prefill_busy_s`` the time its prefill
+    waited behind decode steps, other prompts' chunks and its join.
     """
     req_id: int
     priority: int = 0
@@ -67,3 +75,6 @@ class RequestOutput:
     preemptions: int = 0
     restores: list = field(default_factory=list)
     completed_iter: Optional[int] = None
+    t_submit: Optional[float] = None
+    t_first_chunk: Optional[float] = None
+    prefill_busy_s: float = 0.0
