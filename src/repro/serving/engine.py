@@ -17,6 +17,7 @@ import threading
 import time
 import warnings
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Optional
 
 import jax
@@ -616,8 +617,11 @@ def connect_pools(pools: list["HostKVPool"]) -> None:
 @dataclass
 class PrefillResult:
     first_token: int
-    kv_k: np.ndarray            # (L, S, KV, Dh) full-depth KV of the request
-    kv_v: np.ndarray
+    # (L, S, KV, Dh) full-depth KV of the request on the host, or None
+    # when the prefill staged it into device pages: ``host_kv`` reads
+    # either
+    kv_k: Optional[np.ndarray]
+    kv_v: Optional[np.ndarray]
     prompt_len: int
     reused_blocks: int
     new_blocks: int
@@ -641,6 +645,20 @@ class PrefillResult:
             self.page_pool.release(self.pages)
             self._pages_adopted = True
 
+    def host_kv(self) -> tuple[np.ndarray, np.ndarray]:
+        """The request's (L, S, KV, Dh) KV on the host: the bytes a
+        prefill that could not stage read back, else a read of its staged
+        page run, which must still hold what was staged."""
+        if self.kv_k is not None:
+            return self.kv_k, self.kv_v
+        pp = self.page_pool
+        if pp is None or self.pages is None or \
+                pp.gens_of(self.pages) != self.page_gens:
+            raise RuntimeError(
+                "this PrefillResult's KV lived only in its staged page run, "
+                "which was released and re-used; re-prefill instead")
+        return pp.read_seq(self.pages, self.prompt_len)
+
 
 def paged_supported(cfg: ModelConfig) -> bool:
     """The paged decode substrate covers uniform attention-only stacks;
@@ -650,19 +668,37 @@ def paged_supported(cfg: ModelConfig) -> bool:
     return cfg.attention_layers == cfg.n_layers and not cfg.encoder_layers
 
 
-def stage_run(pool, hash_ids: list[int], k_full: np.ndarray,
-              v_full: np.ndarray, S: int,
+@partial(jax.jit, static_argnums=2)
+def _device_tokens(x, lo, n: int):
+    """Tokens [lo, lo + n) of device KV, (L, S, KV, Dh) or a B=1 dense
+    cache (L, 1, S, KV, Dh), as (L, n, KV, Dh). ``lo`` is traced: one
+    program per (S, n) serves a block wherever it lies in the prompt."""
+    x = x.reshape((x.shape[0], -1) + x.shape[-2:])
+    return jax.lax.dynamic_slice_in_dim(x, lo, n, axis=1)
+
+
+def _kv_tokens(k, v, lo: int, hi: int):
+    """Tokens [lo, hi) of KV on the host (numpy slices) or on the device
+    (``_device_tokens``)."""
+    if isinstance(k, jax.Array):
+        return _device_tokens(k, lo, hi - lo), _device_tokens(v, lo, hi - lo)
+    return k[:, lo:hi], v[:, lo:hi]
+
+
+def stage_run(pool, hash_ids: list[int], k_full, v_full, S: int,
               bank: Optional[int] = None) -> Optional[list[int]]:
     """Stage a request's KV into a ``DevicePagePool`` page run (§3 step 2:
     fresh pages written layer-stacked; step 1: registered prefix runs
     ADOPTED — the physical pages are shared with every slot on the same
     hash chain, no bytes move). Full 512-token blocks register under
     their chain hash for later requests; the partial tail gets private
-    pages. On a banked (mesh-sharded) pool the whole run lives in ONE
-    data-shard bank — ``bank=None`` picks the bank with the deepest
-    registered prefix. The caller owns one reference per returned page.
-    Returns None (nothing held) if the pool can't fit the run even after
-    evicting registry-only runs."""
+    pages. ``k_full``/``v_full`` hold the KV on the host (L, S, KV, Dh),
+    or on the device: a prefill's dense cache, whose pages are then
+    written device to device. On a banked (mesh-sharded) pool the whole
+    run lives in ONE data-shard bank — ``bank=None`` picks the bank with
+    the deepest registered prefix. The caller owns one reference per
+    returned page. Returns None (nothing held) if the pool can't fit the
+    run even after evicting registry-only runs."""
     if pool is None:
         return None
     if bank is None:
@@ -676,16 +712,15 @@ def stage_run(pool, hash_ids: list[int], k_full: np.ndarray,
         for i in range(adopted, n_full):
             run = pool.alloc(pool.pages_per_block, bank=bank)
             held += run
-            pool.write_run(run, k_full[:, i * B:(i + 1) * B],
-                           v_full[:, i * B:(i + 1) * B])
+            pool.write_run(run, *_kv_tokens(k_full, v_full, i * B,
+                                            (i + 1) * B))
             pool.register_block(hash_ids[i], run)
             pages += run
         tail = S - n_full * B
         if tail > 0:
             run = pool.alloc(pool.pages_for(tail), bank=bank)
             held += run
-            pool.write_run(run, k_full[:, n_full * B:S],
-                           v_full[:, n_full * B:S])
+            pool.write_run(run, *_kv_tokens(k_full, v_full, n_full * B, S))
             pages += run
         return pages
     except MemoryError:
@@ -820,7 +855,8 @@ class PrefillWorker:
                              ssd_loaded_blocks=0, overlapped_requests=0,
                              fallback_blocks=0, peer_blocks=0,
                              skipped_blocks=0, chunks=0, h2d_bytes=0,
-                             d2h_bytes=0, put_bytes=0, stage_bytes=0)
+                             d2h_bytes=0, put_bytes=0, stage_bytes=0,
+                             device_stages=0, host_stages=0)
         # serving-loop hook: called with the page count a stage would pin;
         # returning False skips staging (the join stages later) so staged
         # results can't eat the decode batch's reserved growth pages
@@ -860,29 +896,36 @@ class PrefillWorker:
         return KVCache(k=kv.k.at[:, 0, lo:hi].set(jnp.asarray(k_np)),
                        v=kv.v.at[:, 0, lo:hi].set(jnp.asarray(v_np)))
 
-    def _readback(self, logits, caches, info: dict):
-        """The first token and the whole dense cache, device to host."""
-        with span("prefill.readback", **info):
-            first = int(jnp.argmax(logits[0, -1]))
-            k_full = np.asarray(caches.kv.k[:, 0])
-            v_full = np.asarray(caches.kv.v[:, 0])
-        self.counters["d2h_bytes"] += k_full.nbytes + v_full.nbytes
-        return first, k_full, v_full
+    def _read(self, kv: KVCache, spans) -> list:
+        """Host copies of the dense cache's tokens [lo, hi) for each span
+        (``lo`` on a block boundary), read in pieces of at most a block:
+        one program per prompt length and piece size, the shapes staging
+        uses, wherever a piece lies; every transfer in flight at once."""
+        B = BLOCK_TOKENS
+        cuts = [[(a, min(a + B, hi)) for a in range(lo, hi, B)]
+                for lo, hi in spans]
+        host = iter(jax.device_get([
+            (_device_tokens(kv.k, a, b - a), _device_tokens(kv.v, a, b - a))
+            for cut in cuts for a, b in cut]))
+        out = []
+        for cut in cuts:
+            ks, vs = zip(*(next(host) for _ in cut))
+            out.append((np.concatenate(ks, axis=1),
+                        np.concatenate(vs, axis=1)))
+        return out
 
-    def _put(self, hash_ids, k_full, v_full, i: int, j: int) -> None:
-        """Blocks [i, j) of the dense cache into the host pool."""
-        sl = slice(i * BLOCK_TOKENS, j * BLOCK_TOKENS)
-        k, v = k_full[:, sl], v_full[:, sl]
+    def _put(self, hash_ids, k, v, i: int, j: int) -> None:
+        """Blocks [i, j), held in ``k``/``v``, into the host pool."""
         self.pool.put(hash_ids[i:j], k, v, start_pos=i)
         self.counters["put_bytes"] += k.nbytes + v.nbytes
 
-    def _stage(self, hash_ids, k_full, v_full, S) -> Optional[list[int]]:
+    def _stage(self, hash_ids, kv: KVCache, S) -> Optional[list[int]]:
         pp = self.page_pool
         if pp is None or (self.stage_guard is not None
                           and not self.stage_guard(pp.pages_for(S))):
             return None
         written = pp.pages_written
-        pages = stage_run(pp, hash_ids, k_full, v_full, S)
+        pages = stage_run(pp, hash_ids, kv.k, kv.v, S)
         if pages is not None:
             self.counters["stage_bytes"] += \
                 (pp.pages_written - written) * pp.page_bytes
@@ -891,17 +934,45 @@ class PrefillWorker:
     def stats(self) -> dict:
         """Unified snapshot (cross-component ``stats()`` protocol):
         lifetime counters, the KV bytes of each tier transfer among them
-        (``h2d_bytes`` host-pool hits uploaded, ``d2h_bytes`` dense caches
-        read back, ``put_bytes`` stored into the host pool,
-        ``stage_bytes`` written into device pages)."""
+        (``h2d_bytes`` host-pool hits uploaded, ``d2h_bytes`` read back
+        from the dense cache, ``put_bytes`` stored into the host pool,
+        ``stage_bytes`` written into device pages), and how each finished
+        prefill handed its KV on (``device_stages`` into pages from the
+        device, ``host_stages`` read back whole for a later join)."""
         return dict(self.counters)
 
-    def _stage_result(self, hash_ids, k_full, v_full, S, info: dict) -> dict:
-        """PrefillResult kwargs for the staged page run (+ generation
-        snapshot so late re-joins can detect recycled pages)."""
+    def _finish(self, logits, kv: KVCache, hash_ids, runs, S: int,
+                info: dict) -> dict:
+        """The end of a prefill, on both paths: read back the first token
+        and only the fresh full blocks ``runs`` ([(i, j)] block ranges)
+        that the host pool stores, stage the pages straight from the dense
+        device cache, and store the blocks. When the device cannot take
+        the run (no page pool, the stage guard, page OOM), the whole cache
+        comes back to the host and the join stages it later. Returns the
+        ``PrefillResult`` fields it settles (with a generation snapshot of
+        the staged run, so that late re-joins can detect recycled
+        pages)."""
+        B = BLOCK_TOKENS
+        with span("prefill.readback", **info):
+            first = int(jnp.argmax(logits[0, -1]))
+            blocks = self._read(kv, [(i * B, j * B) for i, j in runs])
         with span("prefill.stage", **info):
-            pages = self._stage(hash_ids, k_full, v_full, S)
+            pages = self._stage(hash_ids, kv, S)
+        k_full = v_full = None
+        if pages is None:
+            with span("prefill.readback", **info):
+                k_full, v_full = self._read(kv, [(0, S)])[0]
+            self.counters["d2h_bytes"] += k_full.nbytes + v_full.nbytes
+        self.counters["d2h_bytes"] += sum(k.nbytes + v.nbytes
+                                          for k, v in blocks)
+        if runs:
+            with span("prefill.store", **info):
+                for (i, j), (k, v) in zip(runs, blocks):
+                    self._put(hash_ids, k, v, i, j)
+        self.counters["host_stages" if pages is None
+                      else "device_stages"] += 1
         return dict(
+            first_token=first, kv_k=k_full, kv_v=v_full, prompt_len=S,
             hash_ids=hash_ids, pages=pages, page_pool=self.page_pool,
             page_gens=None if pages is None
             else self.page_pool.gens_of(pages))
@@ -969,26 +1040,20 @@ class PrefillWorker:
             logits, caches = self._chunk_extend(t, caches, lo, hi, info)
             if hi < S:
                 yield               # suspension point for the serving loop
-        first, k_full, v_full = self._readback(logits, caches, info)
-
-        # layer-wise store-back of every fresh full block (§5.2: on TPU the
-        # per-layer store launches as soon as that layer's KV exists; here
-        # the ordering contract is preserved by storing from the scanned
+        # store-back of every fresh full block (§5.2: on TPU the per-layer
+        # store launches as soon as that layer's KV exists; here the
+        # ordering contract is preserved by storing from the scanned
         # per-layer stack)
         n_total = len(hash_ids)
-        if n_total > n_hit:
-            with span("prefill.store", **info):
-                self._put(hash_ids, k_full, v_full, n_hit, n_total)
+        runs = [(n_hit, n_total)] if n_total > n_hit else []
+        done = self._finish(logits, caches.kv, hash_ids, runs, S, info)
         n_peer = self.pool.peer_blocks_fetched - peer0
         self.counters["reused_blocks"] += n_hit
         self.counters["computed_tokens"] += S - prefix_tokens
         self.counters["requests"] += 1
         self.counters["peer_blocks"] += n_peer
-        return PrefillResult(first_token=first, kv_k=k_full, kv_v=v_full,
-                             prompt_len=S, reused_blocks=n_hit,
-                             new_blocks=n_total - n_hit, peer_blocks=n_peer,
-                             **self._stage_result(hash_ids, k_full, v_full, S,
-                                                  info))
+        return PrefillResult(reused_blocks=n_hit, new_blocks=n_total - n_hit,
+                             peer_blocks=n_peer, **done)
 
     def _chunks_overlapped(self, tokens: np.ndarray, hash_ids: list[int],
                            plan: FetchPlan, info: dict):
@@ -1081,24 +1146,23 @@ class PrefillWorker:
             logits, caches = self._chunk_extend(t, caches, lo, hi, info)
             if hi < S:
                 yield
-        first, k_full, v_full = self._readback(logits, caches, info)
-
         # store-back: the RECOMPUTED head runs (chunk-skipped DRAM blocks
         # are already pool-resident) and the fresh suffix blocks
         n_total = len(hash_ids)
-        with span("prefill.store", **info):
-            i = d0
-            while i < s:
-                if plan.tiers[i] == "dram":
-                    i += 1
-                    continue
-                j = i
-                while j < s and plan.tiers[j] != "dram":
-                    j += 1
-                self._put(hash_ids, k_full, v_full, i, j)
-                i = j
-            if n_total > usable:
-                self._put(hash_ids, k_full, v_full, usable, n_total)
+        runs = []
+        i = d0
+        while i < s:
+            if plan.tiers[i] == "dram":
+                i += 1
+                continue
+            j = i
+            while j < s and plan.tiers[j] != "dram":
+                j += 1
+            runs.append((i, j))
+            i = j
+        if n_total > usable:
+            runs.append((usable, n_total))
+        done = self._finish(logits, caches.kv, hash_ids, runs, S, info)
 
         reused = d0 + n_skip + n_tail
         n_peer = self.pool.peer_blocks_fetched - peer0
@@ -1110,13 +1174,10 @@ class PrefillWorker:
         self.counters["fallback_blocks"] += n - usable
         self.counters["peer_blocks"] += n_peer
         self.counters["skipped_blocks"] += n_skip
-        return PrefillResult(first_token=first, kv_k=k_full, kv_v=v_full,
-                             prompt_len=S, reused_blocks=reused,
+        return PrefillResult(reused_blocks=reused,
                              new_blocks=len(hash_ids) - reused,
                              ssd_blocks=n_tail, peer_blocks=n_peer,
-                             overlapped=True, skipped_blocks=n_skip,
-                             **self._stage_result(hash_ids, k_full, v_full, S,
-                                                  info))
+                             overlapped=True, skipped_blocks=n_skip, **done)
 
 
 @dataclass
@@ -1255,8 +1316,8 @@ class DecodeWorker:
                 # full depth + one extra sequence of staging headroom
                 # (registry runs are evictable on top, so this bound
                 # holds under sharing too). Never sized to fill HBM:
-                # each step and each staging write still copies the
-                # whole slab, so a second copy must fit beside it.
+                # each step still copies the whole slab, so a second
+                # copy must fit beside it.
                 per_seq = (max_len + page_tokens - 1) // page_tokens
                 page_pool = DevicePagePool(
                     cfg, n_pages=1 + (max_batch // d + 1) * per_seq,
@@ -1376,8 +1437,8 @@ class DecodeWorker:
             self.counters["zero_copy_joins"] += 1
             return pages
         hash_ids = pres.hash_ids if pres.hash_ids is not None else []
-        pages = stage_run(pp, hash_ids, pres.kv_k, pres.kv_v,
-                          pres.prompt_len, bank=bank)
+        pages = stage_run(pp, hash_ids, *pres.host_kv(), pres.prompt_len,
+                          bank=bank)
         if pages is None:
             raise MemoryError("device page pool cannot hold the request")
         if pres.page_pool is pp:
@@ -1464,9 +1525,9 @@ class DecodeWorker:
         else:
             if self.caches.kv is not None:
                 kv = self.caches.kv
-                kv = KVCache(
-                    k=kv.k.at[:, slot, :L].set(jnp.asarray(pres.kv_k[:, :L])),
-                    v=kv.v.at[:, slot, :L].set(jnp.asarray(pres.kv_v[:, :L])))
+                k, v = pres.host_kv()
+                kv = KVCache(k=kv.k.at[:, slot, :L].set(jnp.asarray(k[:, :L])),
+                             v=kv.v.at[:, slot, :L].set(jnp.asarray(v[:, :L])))
                 self.caches = self.caches._replace(kv=kv)
             self.caches = self.caches._replace(
                 length=self.caches.length.at[slot].set(L))

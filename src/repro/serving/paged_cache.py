@@ -199,6 +199,43 @@ def gather_kv(cache: PagedKVCache, max_tokens: int):
     return k, v
 
 
+def page_write(page_tokens: int, *, per_page: bool, sharding=None):
+    """The jitted page write behind ``DevicePagePool.write_run``:
+    ``(k_pages, v_pages, idx, k, v) -> (k_pages, v_pages)`` with (L, T,
+    KV, Dh) KV written into the pages ``idx`` (a partial tail page
+    zero-padded). Both slabs are donated, so the write happens in place;
+    on a mesh, ``sharding`` is pinned as the slabs' output sharding.
+
+    ``per_page``: one ``dynamic_update_slice`` per page, which touches
+    only that page where pages are contiguous in the slab's layout.
+    Otherwise one scatter: where the device layout puts the page axis
+    minor-most, every page is strided over the whole slab, and one pass
+    beats a pass per page. On a TPU v5e: 1.0 against 17.6 ms per
+    8-page write into qwen2.5-3b's slabs (pages major), 26 against 333 ms
+    into phi3-mini's (pages minor)."""
+    pt = page_tokens
+
+    def write(k_pages, v_pages, idx, k, v):
+        n = idx.shape[0]
+
+        def paged(x):
+            x = x.astype(k_pages.dtype)
+            x = jnp.pad(x, ((0, 0), (0, n * pt - x.shape[1]), (0, 0), (0, 0)))
+            return x.reshape((x.shape[0], n, pt) + x.shape[2:])
+
+        k, v = paged(k), paged(v)
+        if not per_page:
+            return k_pages.at[:, idx].set(k), v_pages.at[:, idx].set(v)
+        for i in range(n):
+            at = (0, idx[i], 0, 0, 0)
+            k_pages = jax.lax.dynamic_update_slice(k_pages, k[:, i:i + 1], at)
+            v_pages = jax.lax.dynamic_update_slice(v_pages, v[:, i:i + 1], at)
+        return k_pages, v_pages
+
+    out = None if sharding is None else (sharding, sharding)
+    return jax.jit(write, donate_argnums=(0, 1), out_shardings=out)
+
+
 # ---------------------------------------------------------------------------
 # shared device page pool (the engine's paged decode substrate)
 # ---------------------------------------------------------------------------
@@ -269,10 +306,16 @@ class DevicePagePool:
             from jax.sharding import NamedSharding, PartitionSpec
             self._sharding = NamedSharding(
                 mesh, PartitionSpec(None, "data", None, "model", None))
+            self._src_sharding = NamedSharding(
+                mesh, PartitionSpec(None, None, "model", None))
             self.k_pages = jax.device_put(jnp.zeros(shape, DTYPE),
                                           self._sharding)
             self.v_pages = jax.device_put(jnp.zeros(shape, DTYPE),
                                           self._sharding)
+        # the device's layout for the slabs decides how a page is written
+        pages_minor = self.k_pages.format.layout.major_to_minor[-1] == 1
+        self._write = page_write(page_tokens, per_page=not pages_minor,
+                                 sharding=self._sharding)
         # reentrant: alloc -> eviction -> unregister -> release re-enters
         self._lock = threading.RLock()
         # one free list / registry / LRU per bank; bank 0's objects are
@@ -507,29 +550,26 @@ class DevicePagePool:
             return n, pages
 
     # ---- device writes -------------------------------------------------
-    def write_run(self, pages: list[int], k: np.ndarray,
-                  v: np.ndarray) -> None:
+    def write_run(self, pages: list[int], k, v) -> None:
         """Scatter (L, T, KV, Dh) KV into ``pages`` (T ≤ len(pages)·page).
-        One fused indexed update per array; a partial tail page is
-        zero-padded (fresh pages, nothing to preserve)."""
-        pt = self.page_tokens
-        L, T = k.shape[0], k.shape[1]
-        n = len(pages)
-        assert T <= n * pt, (T, n, pt)
-        pad = n * pt - T
-        k = jnp.asarray(k, self.k_pages.dtype)
-        v = jnp.asarray(v, self.v_pages.dtype)
-        if pad:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        idx = jnp.asarray(pages, jnp.int32)
-        shape = (L, n, pt) + k.shape[2:]
+        ``k``/``v`` are host arrays or device arrays (a prefill's dense
+        cache, sliced). One jitted write (``page_write``) that donates both
+        slabs, so the pages are written in place; a partial tail page is
+        zero-padded (fresh pages, nothing to preserve). Compiles once per
+        (pages, T) shape, whatever the pages' ids or where the KV came
+        from."""
+        assert k.shape[1] <= len(pages) * self.page_tokens, \
+            (k.shape, len(pages), self.page_tokens)
+        idx = np.asarray(pages, np.int32)
+        if self._sharding is not None:
+            # a device source may sit on another device than the slabs:
+            # lay it out as the slabs stripe their KV heads
+            k = jax.device_put(k, self._src_sharding)
+            v = jax.device_put(v, self._src_sharding)
         with self._lock:
-            self.k_pages = self._pin(
-                self.k_pages.at[:, idx].set(k.reshape(shape)))
-            self.v_pages = self._pin(
-                self.v_pages.at[:, idx].set(v.reshape(shape)))
-            self.counters["pages_written"] += n
+            self.k_pages, self.v_pages = self._write(
+                self.k_pages, self.v_pages, idx, k, v)
+            self.counters["pages_written"] += len(pages)
 
     def make_writable(self, page: int) -> int:
         """Copy-on-write: return a page id safe to append into. A page

@@ -519,3 +519,190 @@ def test_overlap_split_prices_skipped_dram_free():
     assert ov.head_recompute == 3
     assert ov.head_skipped == 2
     assert ov.t_head == pytest.approx(0.3)
+
+
+# ------------------------------- prefill → decode handoff on the device ----
+
+def _held_blocks(pool, ids):
+    """The host pool's bytes of each block of ``ids`` it holds."""
+    return {h: pool.get([h]) for h in ids if h in pool.meta}
+
+
+def _fragment(pool, pw, t):
+    """Make the next prefill of ``t`` take the overlapped path with
+    recomputed head runs around chunk-skipped DRAM blocks (the recipe of
+    ``test_chunk_skipping_bit_exact_and_fewer_tokens``)."""
+    ids = prefix_hash_ids(t)
+    pool.meta.touch_keys(ids[2:4])
+    pool.store._read_s_ema = 10.0
+    pw._t_block_ema = 1e-6
+
+
+def _handoff_case(case, rng, vocab):
+    """(history prompts, final prompt, needs the SSD tier) of a case."""
+    a = rng.integers(0, vocab, 1100)
+    return {
+        "cold": ([], a[:1024], False),
+        "tailed": ([], a[:700], False),
+        "partial_hit": ([a], np.concatenate(
+            [a[:BLOCK_TOKENS], rng.integers(0, vocab, 650)]), False),
+        "full_hit": ([a[:1024]], a[:1024], False),
+        "overlapped": (None, rng.integers(0, vocab, 6 * 512 + 100), True),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["cold", "tailed", "partial_hit",
+                                  "full_hit", "overlapped"])
+def test_device_handoff_matches_host_staging(setup, tmp_path, case):
+    """A prefill that stages its pages from the device dense cache writes
+    the same page run as staging from host bytes, leaves the host pool
+    exactly as reading everything back did, and reads back only what the
+    host pool stores."""
+    from repro.serving.engine import stage_run
+    cfg, params = setup
+    history, final, ssd = _handoff_case(case, np.random.default_rng(11),
+                                        cfg.vocab_size)
+    out = {}
+    for side in ("device", "host"):
+        if ssd:
+            pool = HostKVPool(capacity_blocks=2, ssd_capacity_blocks=16,
+                              ssd_dir=str(tmp_path / side),
+                              writeback_batch=1)
+        else:
+            pool = HostKVPool()
+        pp = DevicePagePool(cfg, n_pages=1 + 4 * 32, page_tokens=64) \
+            if side == "device" else None
+        pw = PrefillWorker(params, cfg, pool, prefill_chunk=256,
+                           ssd_mode="overlap", page_pool=pp)
+        if ssd:
+            pw(final)
+            _fragment(pool, pw, final)
+        else:
+            for t in history:
+                pw(t).release_pages()
+        before = pw.stats()
+        res = pw(final)
+        delta = {k: v - before[k] for k, v in pw.stats().items()}
+        out[side] = (pool, pp, res, delta)
+    (pool_d, pp, rd, dd), (pool_h, _, rh, dh) = out["device"], out["host"]
+    S = len(final)
+    assert rd.overlapped == rh.overlapped == ssd
+    assert rd.skipped_blocks == rh.skipped_blocks == (2 if ssd else 0)
+    assert rd.first_token == rh.first_token
+    assert (dd["device_stages"], dd["host_stages"]) == (1, 0)
+    assert (dh["device_stages"], dh["host_stages"]) == (0, 1)
+    # only the stored blocks came back; the host side read the whole cache
+    assert dd["d2h_bytes"] == dd["put_bytes"] == dh["put_bytes"]
+    assert dh["d2h_bytes"] == \
+        dh["put_bytes"] + rh.kv_k.nbytes + rh.kv_v.nbytes
+    if case == "full_hit":
+        assert dd["put_bytes"] > 0          # the recomputed last block
+    # the page run is the one host bytes stage
+    assert rd.kv_k is None and rd.pages is not None
+    ref = DevicePagePool(cfg, n_pages=1 + 4 * 32, page_tokens=64)
+    ref_pages = stage_run(ref, rh.hash_ids, rh.kv_k, rh.kv_v, S)
+    for got, want in zip(pp.read_seq(rd.pages, S),
+                         ref.read_seq(ref_pages, S)):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(rd.host_kv(), rh.host_kv()):
+        np.testing.assert_array_equal(got, want)
+    # the host pool holds the same blocks, byte for byte
+    ids = rh.hash_ids
+    held_d, held_h = _held_blocks(pool_d, ids), _held_blocks(pool_h, ids)
+    assert sorted(held_d) == sorted(held_h) and held_d
+    for h in held_h:
+        for got, want in zip(held_d[h], held_h[h]):
+            np.testing.assert_array_equal(got, want)
+    rd.release_pages()
+    pool_d.close()
+    pool_h.close()
+
+
+def test_page_oom_falls_back_to_host_bytes(setup):
+    """A page pool too small for the run: the prefill reads its whole
+    cache back too, counted as a host stage, and ``host_kv`` gives those
+    bytes."""
+    cfg, params = setup
+    t = np.random.default_rng(12).integers(0, cfg.vocab_size, 700)
+    pp = DevicePagePool(cfg, n_pages=4, page_tokens=64)
+    pw = PrefillWorker(params, cfg, HostKVPool(), prefill_chunk=256,
+                       page_pool=pp)
+    r = pw(t)
+    assert r.pages is None and r.kv_k.shape[1] == len(t)
+    st = pw.stats()
+    assert (st["device_stages"], st["host_stages"]) == (0, 1)
+    assert st["d2h_bytes"] == st["put_bytes"] + r.kv_k.nbytes + r.kv_v.nbytes
+    assert st["put_bytes"] > 0              # its one block, read first
+    assert r.host_kv()[0] is r.kv_k
+    pp.check_leaks()
+
+
+def test_host_kv_of_a_recycled_run_raises(setup):
+    """``host_kv`` of a device-staged result reads its pages while they
+    hold what was staged, and refuses once they were freed and re-used."""
+    from repro.serving.request import ServingRequest
+    cfg, params = setup
+    t = np.random.default_rng(13).integers(0, cfg.vocab_size, 600)
+    pp = DevicePagePool(cfg, n_pages=1 + 3 * 16, page_tokens=64)
+    pw = PrefillWorker(params, cfg, HostKVPool(), prefill_chunk=256,
+                       page_pool=pp)
+    dw = DecodeWorker(params, cfg, max_batch=1, max_len=1024,
+                      substrate="paged", page_pool=pp)
+    r = pw(t)
+    k, _ = r.host_kv()
+    assert k.shape[1] == len(t)
+    dw.join(ServingRequest(req_id=0, tokens=t, max_new=2), r)
+    while dw.n_active:
+        dw.step()
+    pp.alloc(pp.free_pages)                 # recycle every freed page
+    with pytest.raises(RuntimeError, match="re-used"):
+        r.host_kv()
+
+
+@pytest.mark.parametrize("per_page", [True, False])
+def test_page_write_donates_both_slabs(per_page):
+    """The compiled page write aliases both slabs to its outputs (no
+    slab-sized copy), and either form writes the same bytes."""
+    from repro.serving.paged_cache import page_write
+    pool = _tiny_pool(n_pages=24)
+    k, v = _rand_kv(3 * 64 + 10, seed=4)
+    idx = np.asarray([5, 2, 17, 9], np.int32)
+    write = page_write(64, per_page=per_page)
+    text = write.lower(pool.k_pages, pool.v_pages, idx, k, v).compile() \
+        .as_text()
+    assert "input_output_alias={ {0}: (0, {}, may-alias), " \
+        "{1}: (1, {}, may-alias) }" in text
+    kp, vp = write(pool.k_pages, pool.v_pages, idx, k, v)
+    assert pool.k_pages.is_deleted()        # donated
+    for got, want in ((kp, k), (vp, v)):
+        got = np.asarray(got, np.float32)[:, idx].reshape(
+            k.shape[0], -1, *k.shape[2:])
+        want = np.asarray(jnp.asarray(want, jnp.bfloat16), np.float32)
+        np.testing.assert_array_equal(got[:, :want.shape[1]], want)
+        assert not got[:, want.shape[1]:].any()   # zero-padded tail page
+
+
+def test_staging_at_other_offsets_adds_no_compile():
+    """Blocks staged from a device dense cache compile one program per
+    (prompt length, block shape): the same shapes at other offsets, or
+    with other page ids, reuse them."""
+    from repro.serving.engine import _device_tokens, stage_run
+    L, KV, Dh = CFG.attention_layers, CFG.n_kv_heads, CFG.head_dim
+    S = 3 * BLOCK_TOKENS + 100
+    pool = _tiny_pool(n_pages=1 + 2 * 28)
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    dense = [jax.random.normal(kk, (L, 1, S, KV, Dh), jnp.bfloat16)
+             for kk in keys]
+    pages = stage_run(pool, [1, 2, 3], dense[0], dense[1], S)
+    n_write, n_take = pool._write._cache_size(), _device_tokens._cache_size()
+    assert n_write == 2                     # a full block, the tail
+    k, v = pool.read_seq(pages, S)
+    np.testing.assert_array_equal(k, np.asarray(dense[0][:, 0]))
+    np.testing.assert_array_equal(v, np.asarray(dense[1][:, 0]))
+    again = stage_run(pool, [4, 5, 6], dense[2], dense[3], S)
+    for lo in (0, 100, BLOCK_TOKENS, 2 * BLOCK_TOKENS):
+        _device_tokens(dense[2], lo, BLOCK_TOKENS)
+    assert pool._write._cache_size() == n_write
+    assert _device_tokens._cache_size() == n_take
+    pool.release(pages)
+    pool.release(again)

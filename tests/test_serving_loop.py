@@ -38,11 +38,11 @@ def _mk(cfg, params, *, max_batch=4, max_len=512, n_pages=None,
     return pws, dw, pp
 
 
-def _oracle(cfg, params, reqs, max_new):
+def _oracle(cfg, params, reqs, max_new, max_len=512):
     """Request-at-a-time reference streams (fresh engines, one at a time)."""
     pool = HostKVPool()
     pw = PrefillWorker(params, cfg, pool, prefill_chunk=64)
-    dw = DecodeWorker(params, cfg, max_batch=1, max_len=512)
+    dw = DecodeWorker(params, cfg, max_batch=1, max_len=max_len)
     out = {}
     for rid, toks in reqs.items():
         res = pw(toks)
@@ -179,7 +179,7 @@ def test_chunked_prefill_resumable_matches_blocking(setup):
     pw2 = PrefillWorker(params, cfg, pool2, prefill_chunk=64)
     ref = pw2(toks)
     assert cp.result.first_token == ref.first_token
-    np.testing.assert_array_equal(cp.result.kv_k, ref.kv_k)
+    np.testing.assert_array_equal(cp.result.host_kv()[0], ref.host_kv()[0])
 
 
 # ---------------------------------------------------------------------------
@@ -216,6 +216,42 @@ def test_loop_mixed_load_bit_exact_with_thread_fed_arrivals(setup):
         assert loop.outputs[i].tokens == oracle[i], f"req {i} diverged"
     pp.check_leaks()                         # clean shutdown, nothing pinned
     assert stats["tbt_n"] > 0 and stats["tbt_p99_s"] >= stats["tbt_p50_s"]
+
+
+@pytest.mark.parametrize("stage", ["device", "refused"])
+def test_loop_tokens_whether_prefills_stage_on_device_or_not(setup, stage):
+    """Prefills that stage their pages straight from the device, and
+    prefills whose stage the guard refuses (the whole cache read back,
+    staged later by the join through the host path), serve the
+    request-at-a-time oracle's tokens."""
+    cfg, params = setup
+    pws, dw, pp = _mk(cfg, params, max_len=1024, n_pages=1 + 6 * 16,
+                      n_workers=1, chunk=128)
+    loop = ServingLoop(pws, dw, chunks_per_iter=1)
+    if stage == "refused":
+        pws[0].stage_guard = lambda n_pages: False
+    rng = np.random.default_rng(14)
+    shared = rng.integers(0, cfg.vocab_size, 512)
+    reqs = {i: np.concatenate([shared, rng.integers(0, cfg.vocab_size,
+                                                    60 + 150 * i)])
+            for i in range(3)}
+    reqs[3] = rng.integers(0, cfg.vocab_size, 700)
+    for i, t in reqs.items():
+        assert loop.submit(_req(i, t, 5))
+    while not loop.idle:
+        loop.iterate()
+    oracle = _oracle(cfg, params, reqs, max_new=5, max_len=1024)
+    for i in reqs:
+        assert loop.outputs[i].tokens == oracle[i], f"req {i} diverged"
+    st, dst = pws[0].stats(), dw.stats()
+    n = len(reqs)
+    if stage == "device":
+        assert (st["device_stages"], st["host_stages"]) == (n, 0)
+        assert dst["zero_copy_joins"] == n
+    else:
+        assert (st["device_stages"], st["host_stages"]) == (0, n)
+        assert dst["staged_joins"] == n
+    pp.check_leaks()
 
 
 def test_loop_interleaves_prefill_chunks_between_decode_steps(setup):

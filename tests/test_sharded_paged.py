@@ -390,3 +390,57 @@ for arm in ("reload", "recompute"):
 print("OK")
 """, devices=4)
     assert out.count("match: True") == 2, out
+
+
+def test_mesh_device_staged_run_lands_in_its_bank():
+    """On a (2,2) mesh, a prefill staged straight from its device dense
+    cache lands in ONE bank, the one with the deepest registered prefix
+    (else the one with the most free pages), holds the bytes a host-side
+    prefill computes, and leaves both slabs on their mesh sharding; the
+    one-pass scatter form of the page write stores the same bytes."""
+    run_subprocess(_SUB_PRELUDE + """
+from repro.serving.paged_cache import page_write
+cfg = dataclasses.replace(get_config("smollm-360m").reduced(),
+                          n_heads=16, n_kv_heads=4)
+params = init_params(cfg, jax.random.PRNGKey(0))
+rng = np.random.default_rng(15)
+warm, cold = (rng.integers(0, cfg.vocab_size, n) for n in (700, 600))
+oracle = PrefillWorker(params, cfg, HostKVPool(), prefill_chunk=256)
+want = {id(t): oracle(t) for t in (warm, cold)}
+
+pp = DevicePagePool(cfg, n_pages=32, mesh=make_decode_mesh(2, 2),
+                    page_tokens=64)
+# bank 1 holds the warm prompt's first block: its run goes there
+rh = want[id(warm)]
+blk = pp.alloc(pp.pages_per_block, bank=1)
+pp.write_run(blk, rh.kv_k[:, :512], rh.kv_v[:, :512])
+pp.register_block(rh.hash_ids[0], blk)
+pp.release(blk)
+pw = PrefillWorker(params, cfg, HostKVPool(), prefill_chunk=256,
+                   page_pool=pp)
+for t, bank in ((warm, 1), (cold, 0)):
+    r = pw(t)
+    assert r.kv_k is None and r.pages, r
+    assert {pp.bank_of(p) for p in r.pages} == {bank}, (bank, r.pages)
+    for got, ref in zip(r.host_kv(), want[id(t)].host_kv()):
+        np.testing.assert_array_equal(got, ref)
+    assert pp.k_pages.sharding == pp._sharding
+    assert pp.v_pages.sharding == pp._sharding
+    r.release_pages()
+st = pw.stats()
+assert (st["device_stages"], st["host_stages"]) == (2, 0), st
+
+# the two forms of the write agree on the mesh
+idx = np.asarray([3, 20, 9], np.int32)
+k, v = rh.kv_k[:, :150], rh.kv_v[:, :150]
+out = []
+for per_page in (True, False):
+    w = page_write(64, per_page=per_page, sharding=pp._sharding)
+    kp, vp = w(pp.k_pages.copy(), pp.v_pages.copy(), idx, k, v)
+    assert kp.sharding == pp._sharding
+    out.append((np.asarray(kp), np.asarray(vp)))
+for a, b in zip(*out):
+    np.testing.assert_array_equal(a, b)
+pp.check_leaks()
+print("OK")
+""", devices=4)

@@ -18,11 +18,14 @@ import pytest
 from repro.kernels.flash_prefill.kernel import flash_prefill
 from repro.kernels.paged_attention.kernel import (paged_attention,
                                                   paged_attention_layers)
+from repro.serving.paged_cache import page_write
 
 # (H, KV, D): qwen2.5-3b's grouped heads, and smollm-360m's unpadded ones
 WIDTHS = {"qwen2.5-3b": (16, 2, 128), "smollm-360m": (15, 5, 64)}
 B, PAGE, N_PAGES, MAX_PAGES, L = 8, 64, 289, 32, 36
 SQ, SK = 256, 1024          # one prefill chunk over its 1k-token prefix
+# (L, pages, KV, D) of the benchmark's page slabs
+SLABS = {"qwen2.5-3b": (36, 1153, 2, 128), "phi3-mini": (32, 113, 32, 96)}
 
 
 @pytest.fixture(scope="module")
@@ -83,3 +86,27 @@ def test_flash_prefill_compiles(arch, one_chip):
         ((1, SK, KV, D), jnp.bfloat16),
         ((1, SK, KV, D), jnp.bfloat16)], one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", sorted(SLABS))
+def test_page_write_compiles_in_place(arch, one_chip):
+    """The page write takes the form ``DevicePagePool`` picks from the
+    slab's device layout (phi3-mini's puts the page axis minor-most), and
+    compiles with both slabs aliased to its outputs; per page, at qwen's
+    widths, with no slab-sized temporary either."""
+    L, P, KV, D = SLABS[arch]
+    slab = (L, P, PAGE, KV, D)
+    zeros = jax.jit(lambda: jnp.zeros(slab, jnp.bfloat16),
+                    out_shardings=one_chip).lower().compile()
+    pages_minor = zeros.output_formats.layout.major_to_minor[-1] == 1
+    assert pages_minor == (arch == "phi3-mini")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in [
+        (slab, jnp.bfloat16), (slab, jnp.bfloat16), ((8,), jnp.int32),
+        ((L, 512, KV, D), jnp.bfloat16), ((L, 512, KV, D), jnp.bfloat16)]]
+    compiled = page_write(PAGE, per_page=not pages_minor).lower(
+        *args).compile()
+    assert "input_output_alias={ {0}: (0, {}, may-alias), " \
+        "{1}: (1, {}, may-alias) }" in compiled.as_text()
+    if not pages_minor:
+        slab_bytes = L * P * PAGE * KV * D * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes / 100
