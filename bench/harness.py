@@ -9,12 +9,12 @@ import shutil
 import threading
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
-import ml_dtypes
 import numpy as np
 
 from bench import flops, reference, traffic
-from bench.model import load_config, program_config
+from bench.model import load_config
 from bench.spans import CompileCounter, Recorder, instrument
 
 BENCH = Path(__file__).resolve().parent
@@ -48,16 +48,17 @@ def build_engine(cfg, params, eng: dict):
     return pool, pw, dw
 
 
-def warm_up(cfg, pool, pw, dw, work: traffic.Workload, seed: int, log) -> int:
+def warm_up(arch, cfg, pool, pw, dw, work: traffic.Workload, seed: int,
+            log) -> int:
     """Run every shape the window can use once: for each prompt length,
     every prefix-hit depth the traffic can produce (one chunk each: the
     hit path's updates are shaped by the depth) and, to its end, the
     deepest one (the store, the staging writes and the join), then decode
     steps at every table width. Hits are made by putting zero KV for the
-    prompt's leading blocks into the host pool. Returns the prefills run."""
+    prompt's leading blocks into the host pool (``arch.host_kv``). Returns
+    the prefills run."""
     from repro.serving.request import ServingRequest
     rng = np.random.default_rng((int(seed), 99))
-    L, KV, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     finished = []
     n = 0
     for S in work.prompt_lengths:
@@ -66,8 +67,7 @@ def warm_up(cfg, pool, pw, dw, work: traffic.Workload, seed: int, log) -> int:
             toks = rng.integers(0, cfg.vocab_size, S).astype(np.int32)
             if depth:
                 ids = pw.hasher.hash_ids(toks)[:depth // BLOCK]
-                zero = np.zeros((L, depth, KV, Dh), ml_dtypes.bfloat16)
-                pool.put(ids, zero, zero)
+                pool.put(ids, *arch.host_kv(cfg, depth))
             cp = pw.start(toks)
             n += 1
             if depth == deepest:
@@ -274,6 +274,15 @@ class Context:
         self.__dict__.update(kw)
 
 
+def counts(arch) -> SimpleNamespace:
+    """``ctx.flops``: the architecture's least operations and bytes, and
+    the roofline that turns them into seconds."""
+    return SimpleNamespace(prefill_chunk=arch.prefill_chunk,
+                           decode_step=arch.decode_step,
+                           weight_bytes=arch.weight_bytes,
+                           least_seconds=flops.least_seconds)
+
+
 def per_layer(names: list, ctx: Context) -> dict:
     """Each metric's reader, ``bench/metrics/<name>.py``; a reader that
     finds nothing to read returns None and the metric is left out."""
@@ -326,7 +335,7 @@ def pick_sample(recs: list, work: traffic.Workload, prefills: dict,
     return [(r, by_id[r["req_id"]], reused[r["req_id"]]) for r in picks]
 
 
-def check_outputs(shape, seed: int, sample: list, limit: float, log,
+def check_outputs(arch, shape, seed: int, sample: list, limit: float, log,
                   control: bool) -> tuple[bool, dict]:
     """Compare the served tokens of ``sample`` with the reference. With
     ``control`` the fp8 control stands in for the program: its tokens at
@@ -337,7 +346,7 @@ def check_outputs(shape, seed: int, sample: list, limit: float, log,
     served = [np.asarray(r["tokens"], np.int64) for r, _, _ in sample]
     short = sum(len(r["tokens"]) != q.max_new for r, q, _ in sample)
     t0 = time.monotonic()
-    gaps = reference.served_gaps(shape, seed, prompts, served,
+    gaps = reference.served_gaps(arch, shape, seed, prompts, served,
                                  control=control)
     worst = {k: max(float(g.max()) for g in v) for k, v in gaps.items()}
     n_tok = sum(len(g) for g in gaps["served"])
@@ -364,11 +373,10 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     import jax
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.loop import ServingLoop
-    from bench import weights
     from bench.device import peaks
     enable_compile_cache()
-    shape, _ = load_config(cell["config"], path=config_path)
-    cfg = program_config(shape)
+    arch, shape = load_config(cell["config"], path=config_path)
+    cfg = arch.program_config(shape)
     mix = traffic.load_mix(cell["traffic"])
     eng = cell["engine"]
     meminfo = Path("/proc/meminfo")
@@ -376,13 +384,13 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         log("host " + " ".join(meminfo.read_text().split("\n")[:2]))
     work = traffic.make_workload(mix, cell, seed, seconds, shape.vocab_size)
     log(traffic.describe(work))
-    params = weights.program_params(shape, seed, cfg.padded_vocab)
+    params = arch.program_params(shape, seed, cfg.padded_vocab)
     jax.block_until_ready(params)
     log(f"weights made on {device['kind']}")
     pool, pw, dw = build_engine(cfg, params, eng)
     rec = Recorder()
     instrument(rec, [pw], dw, pool)
-    warm_up(cfg, pool, pw, dw, work, seed, log)
+    warm_up(arch, cfg, pool, pw, dw, work, seed, log)
     gc.collect()
     if fault is not None:
         fault(pw, dw)
@@ -445,7 +453,8 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         ctx = Context(cfg=cfg, shape=shape, recorder=rec, window=win,
                       records=recs, counters0=counters0, counters1=counters1,
                       compiles=compiles, reduced=red,
-                      peak=peaks(device["kind"]), flops=flops, limits=limits)
+                      peak=peaks(device["kind"]), flops=counts(arch),
+                      limits=limits)
         names = [(m["name"], m["unit"]) for m in cell["per_layer"]]
         result["metrics"] = per_layer(names, ctx)
         dev.update(busy_s=red.busy_s, window_s=red.window_s)
@@ -465,7 +474,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     gc.collect()
     sample = pick_sample(recs, work, prefills, cell["check"]["sample"],
                          cell["check"]["tokens"], seed)
-    ok, check = check_outputs(shape, seed, sample,
+    ok, check = check_outputs(arch, shape, seed, sample,
                               cell["check"]["max_logit_gap"], log, control)
     ok = ok and attempted > 0
     result["correct"] = bool(ok)

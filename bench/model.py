@@ -1,79 +1,56 @@
 """A configuration file, read into the shapes the benchmark works with.
 
 ``bench/configs/<name>.json`` holds the published ``config.json`` keys as
-they are run. ``Shape`` is what the yardstick (weights, reference, FLOP
-and byte counts) reads; ``program_config`` turns it into the program's own
-``ModelConfig`` for the system under test.
+they are run, and may name its architecture (``"architecture"``, default
+``"dense"``). The architecture is one file, ``bench/arch/<arch>.py``,
+loaded by path, that owns everything the architecture decides:
+
+- ``load(name, raw) -> shape``: a frozen, hashable shape (a jit static
+  argument) with at least ``name``, ``n_layers``, ``d_model``,
+  ``vocab_size``, ``norm_eps`` and ``tie_embeddings``;
+- ``program_config(shape)``: the program's ``ModelConfig``;
+- ``program_params(shape, seed, padded_vocab)``: the program's parameter
+  tree, made on the device;
+- ``layer_weights(shape, root, index)`` and
+  ``layer(x, t, index, *, shape, fp8)``: one layer of the plain reference,
+  told its index, so that stacks mixing layer kinds fit;
+- ``prefill_chunk(shape, lo, hi, last)``, ``decode_step(shape, seq_lens)``
+  and ``weight_bytes(shape)``: the least operations and bytes
+  (``bench/flops.py`` says what is counted);
+- ``host_kv(cfg, tokens)``: zero K and V of ``tokens`` positions as the
+  program's host pool stores them (the warm-up's prefix hits).
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
-from dataclasses import dataclass
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
+ARCH_DIR = BENCH / "arch"
 
 
-@dataclass(frozen=True)
-class Shape:
-    name: str
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab_size: int
-    qkv_bias: bool
-    rope_theta: float
-    norm_eps: float
-    tie_embeddings: bool
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        """bf16 K and V of one token over every layer."""
-        return 2 * 2 * self.n_layers * self.n_kv_heads * self.head_dim
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """The module in the file at ``path``, loaded once per process, so that
+    its jitted functions and its shape class are the same for every
+    caller."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no architecture file {path}")
+    name = f"bench_arch_{path.stem}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod         # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def load_config(name: str, path=None) -> tuple[Shape, dict]:
-    """(shape, the file's whole content) of ``bench/configs/<name>.json``
-    (or of the file at ``path``)."""
+def load_config(name: str, path=None):
+    """(architecture module, shape) of ``bench/configs/<name>.json`` (or of
+    the file at ``path``)."""
     path = Path(path) if path else BENCH / "configs" / f"{name}.json"
     raw = json.loads(path.read_text())
-    assumed = raw.get("assumed", {})
-    heads = raw["num_attention_heads"]
-    shape = Shape(
-        name=name,
-        n_layers=raw["num_hidden_layers"],
-        d_model=raw["hidden_size"],
-        n_heads=heads,
-        n_kv_heads=raw["num_key_value_heads"],
-        head_dim=raw.get("head_dim", assumed.get(
-            "head_dim", raw["hidden_size"] // heads)),
-        d_ff=raw["intermediate_size"],
-        vocab_size=raw["vocab_size"],
-        qkv_bias=bool(raw.get("attention_bias",
-                              assumed.get("attention_bias", False))),
-        rope_theta=float(raw["rope_theta"]),
-        norm_eps=float(raw["rms_norm_eps"]),
-        tie_embeddings=bool(raw["tie_word_embeddings"]),
-    )
-    if raw.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"{name}: only SwiGLU (silu) MLPs are served")
-    return shape, raw
-
-
-def program_config(s: Shape):
-    """The program's ``ModelConfig`` for these shapes."""
-    from repro.configs.base import ModelConfig
-    cfg = ModelConfig(
-        name=s.name, kind="dense", n_layers=s.n_layers, d_model=s.d_model,
-        n_heads=s.n_heads, n_kv_heads=s.n_kv_heads, head_dim=s.head_dim,
-        d_ff=s.d_ff, vocab_size=s.vocab_size, attn_bias=s.qkv_bias,
-        rope_theta=s.rope_theta, norm_eps=s.norm_eps,
-        tie_embeddings=s.tie_embeddings, remat=False)
-    if cfg.padded_heads != s.n_heads:
-        raise ValueError(f"{s.name}: the program would pad {s.n_heads} query "
-                         f"heads to {cfg.padded_heads}; the weights here "
-                         f"are made for unpadded heads")
-    return cfg
+    arch = load_module(ARCH_DIR / f"{raw.get('architecture', 'dense')}.py")
+    return arch, arch.load(name, raw)

@@ -6,11 +6,12 @@ another with each layer's weights made again from the seed
 (``bench.weights``). It imports nothing of the program and reads nothing
 the program made.
 
-The layer (Qwen2 and Phi-3 alike): RMSNorm, q/k/v projections (with bias
-where the configuration has it), rotary embedding by halves at base
-``rope_theta``, causal attention with grouped KV heads, output
-projection, residual; RMSNorm, SwiGLU MLP, residual. Then a final RMSNorm
-and the output head (the embedding transposed where tied).
+The layers are the architecture's (``bench/arch/<arch>.py``: its
+``layer_weights`` and ``layer``, each told the layer's index). This module
+holds what every architecture shares: the embedding, the loop over the
+layers, the final RMSNorm and the output head (the embedding transposed
+where tied), the served tokens' logit gaps, and the helpers a layer uses
+(``mm``, ``q8``, ``rms``, ``rope``).
 
 ``precision="fp8"`` is the control: the same pass with both operands of
 every matrix product rounded to float8 e4m3 (scaled per row and per
@@ -26,12 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
-from bench.model import Shape
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
-Q_BLOCK = 512         # query rows per attention block
 T_BUCKET = 512        # sequences are padded up to a multiple of this
 HEAD_ROWS = 256       # positions per block of the output head
 
@@ -42,25 +41,25 @@ def padded_rows(vocab: int) -> int:
     return -(-vocab // 256) * 256
 
 
-def _q8(x, axis):
+def q8(x, axis):
     """Round to e4m3 with one scale per slice along ``axis`` (kept dims)."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
     scale = jnp.where(amax > 0, amax / F8_MAX, 1.0)
     return (x / scale).astype(F8).astype(jnp.float32) * scale
 
 
-def _mm(a, b, fp8: bool):
+def mm(a, b, fp8: bool):
     """(..., K) @ (K, N) in float32; fp8 rounds a per row, b per column."""
     if fp8:
-        a, b = _q8(a, -1), _q8(b, 0)
+        a, b = q8(a, -1), q8(b, 0)
     return jnp.matmul(a, b, precision=HI)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: (T, heads, Dh); rotation of the two halves of each head."""
     d = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -70,54 +69,8 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], -1)
 
 
-@partial(jax.jit, static_argnames=("shape", "fp8"))
-def _layer(x, t, *, shape: Shape, fp8: bool):
-    """One decoder layer over a whole (T, D) sequence."""
-    s = shape
-    T = x.shape[0]
-    H, KV, Dh = s.n_heads, s.n_kv_heads, s.head_dim
-    pos = jnp.arange(T)
-    a = _rms(x, t["attn_ln"], s.norm_eps)
-    q = _mm(a, t["wq"], fp8)
-    k = _mm(a, t["wk"], fp8)
-    v = _mm(a, t["wv"], fp8)
-    if s.qkv_bias:
-        q, k, v = q + t["bq"], k + t["bk"], v + t["bv"]
-    q = _rope(q.reshape(T, H, Dh), pos, s.rope_theta)
-    k = _rope(k.reshape(T, KV, Dh), pos, s.rope_theta)
-    v = v.reshape(T, KV, Dh)
-    g = H // KV
-    k = jnp.repeat(k, g, axis=1)            # query head h reads KV head h // g
-    v = jnp.repeat(v, g, axis=1)
-    if fp8:
-        k, v = _q8(k, -1), _q8(v, 0)
-    outs = []
-    for q0 in range(0, T, Q_BLOCK):
-        qb = q[q0:q0 + Q_BLOCK]
-        n = q0 + qb.shape[0]                # the keys this block may see
-        if fp8:
-            qb = _q8(qb, -1)
-        sc = jnp.einsum("qhd,khd->hqk", qb, k[:n], precision=HI) / Dh ** 0.5
-        mask = (q0 + jnp.arange(qb.shape[0]))[:, None] >= pos[None, :n]
-        p = jax.nn.softmax(jnp.where(mask[None], sc, -jnp.inf), axis=-1)
-        if fp8:
-            p = _q8(p, -1)
-        outs.append(jnp.einsum("hqk,khd->qhd", p, v[:n], precision=HI))
-    o = jnp.concatenate(outs).reshape(T, H * Dh)
-    x = x + _mm(o, t["wo"], fp8)
-    m = _rms(x, t["mlp_ln"], s.norm_eps)
-    up = jax.nn.silu(_mm(m, t["w1"], fp8)) * _mm(m, t["w3"], fp8)
-    return x + _mm(up, t["w2"], fp8)
-
-
-@partial(jax.jit, static_argnames=("shape",))
-def _layer_weights(root, layer, *, shape: Shape):
-    one = weights.layers_of(shape, root, jnp.reshape(layer, (1,)))
-    return jax.tree.map(lambda a: a[0].astype(jnp.float32), one)
-
-
 @partial(jax.jit, static_argnames=("shape", "rows"))
-def _head(root, *, shape: Shape, rows: int):
+def _head(root, *, shape, rows: int):
     """(D, vocab) float32 output head, published vocabulary only."""
     if shape.tie_embeddings:
         w = weights.embedding(shape, root, rows).T
@@ -127,19 +80,20 @@ def _head(root, *, shape: Shape, rows: int):
 
 
 @partial(jax.jit, static_argnames=("shape", "rows"))
-def _embed(root, ids, *, shape: Shape, rows: int):
+def _embed(root, ids, *, shape, rows: int):
     return weights.embedding(shape, root, rows)[ids].astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("fp8",))
 def _head_rows(h, w, *, fp8: bool):
     """The logits of each row of ``h``."""
-    return _mm(h, w, fp8)
+    return mm(h, w, fp8)
 
 
-def hidden_states(shape: Shape, seed: int, seqs: list, *,
+def hidden_states(arch, shape, seed: int, seqs: list, *,
                   precision: str = "f32") -> list:
-    """Final normed hidden states (T, D) of each token sequence."""
+    """Final normed hidden states (T, D) of each token sequence, through
+    the layers of ``arch`` (``bench/arch/<arch>.py``) one at a time."""
     fp8 = precision == "fp8"
     root = weights.root_key(seed)
     rows = padded_rows(shape.vocab_size)
@@ -151,15 +105,15 @@ def hidden_states(shape: Shape, seed: int, seqs: list, *,
         pad[:T] = ids
         x = _embed(root, jnp.asarray(pad), shape=shape, rows=rows)
         xs.append(x.at[T:].set(0.0))
-    for layer in range(shape.n_layers):
-        t = _layer_weights(root, jnp.uint32(layer), shape=shape)
-        xs = [_layer(x, t, shape=shape, fp8=fp8) for x in xs]
+    for index in range(shape.n_layers):
+        t = arch.layer_weights(shape, root, index)
+        xs = [arch.layer(x, t, index, shape=shape, fp8=fp8) for x in xs]
     fin = weights.final_norm(shape, root).astype(jnp.float32)
-    return [_rms(x[:len(ids)], fin, shape.norm_eps)
+    return [rms(x[:len(ids)], fin, shape.norm_eps)
             for x, ids in zip(xs, seqs)]
 
 
-def served_gaps(shape: Shape, seed: int, prompts: list, served: list, *,
+def served_gaps(arch, shape, seed: int, prompts: list, served: list, *,
                 control: bool = False) -> dict:
     """For each request (prompt, served tokens): at every served position,
     how far the logit of a token lies under the reference's best, in
@@ -179,7 +133,7 @@ def served_gaps(shape: Shape, seed: int, prompts: list, served: list, *,
     w = _head(root, shape=shape, rows=rows)
     chosen = {"served": [np.asarray(o, np.int64) for o in served]}
     if control:
-        hc = hidden_states(shape, seed, seqs, precision="fp8")
+        hc = hidden_states(arch, shape, seed, seqs, precision="fp8")
         chosen["control"] = []
         for h, rows_i in zip(hc, picks):
             out = []
@@ -188,7 +142,7 @@ def served_gaps(shape: Shape, seed: int, prompts: list, served: list, *,
                 out.append(np.asarray(jnp.argmax(z, -1)))
             chosen["control"].append(np.concatenate(out))
         del hc
-    hr = hidden_states(shape, seed, seqs)
+    hr = hidden_states(arch, shape, seed, seqs)
     gaps = {k: [] for k in chosen}
     for i, (h, rows_i) in enumerate(zip(hr, picks)):
         out = {k: [] for k in chosen}

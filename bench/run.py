@@ -4,7 +4,8 @@
         --seconds 51 --trace 0
 
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a model
-configuration (``bench/configs/<config>.json``) under a traffic mix
+configuration (``bench/configs/<config>.json``, whose architecture is
+``bench/arch/<arch>.py``) under a traffic mix
 (``bench/traffic/<traffic>.json``), with the engine settings, offered
 load and limits of ``bench/cells/<cell>.json``. The run:
 

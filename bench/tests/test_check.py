@@ -48,7 +48,7 @@ def _alter_tokens(pw, dw):
     dw.step = altered
 
 
-@pytest.mark.parametrize("traffic", ["sessions", "chat"])
+@pytest.mark.parametrize("traffic", ["sessions", "chat", "cold"])
 def test_sound_run_is_correct_and_control_is_not(traffic):
     r = _run(traffic, seed=2**31 + 11)
     c = r["check"]
@@ -62,7 +62,7 @@ def test_sound_run_is_correct_and_control_is_not(traffic):
         rc["check"]["max_logit_gap"]["limit"]
 
 
-@pytest.mark.parametrize("traffic", ["sessions", "chat"])
+@pytest.mark.parametrize("traffic", ["sessions", "chat", "cold"])
 def test_altered_token_is_not_correct(traffic):
     r = _run(traffic, seed=7, fault=_alter_tokens)
     assert not r["correct"]

@@ -15,9 +15,9 @@ def _cell(name):
     return json.loads((BENCH / "cells" / f"{name}.json").read_text())
 
 
-
 @pytest.mark.parametrize("mix,cell", [("sessions", "qwen2_5_3b.sessions"),
-                                      ("chat", "phi3_mini_4k.chat")])
+                                      ("chat", "phi3_mini_4k.chat"),
+                                      ("cold", "qwen2_5_3b.cold")])
 def test_same_seed_same_inputs_and_seeds_share_the_schedule(mix, cell):
     m, c = traffic.load_mix(mix), _cell(cell)
     big = 2**33 + 5
@@ -47,6 +47,24 @@ def test_sessions_share_prefixes():
     assert all(0 <= d < S for S in w.prompt_lengths
                for d in w.hit_depths[S])
     assert any(d > 0 for S in w.prompt_lengths for d in w.hit_depths[S])
+
+
+def test_cold_shares_nothing_on_the_sessions_schedule():
+    c = _cell("qwen2_5_3b.cold")
+    cold = traffic.make_workload(traffic.load_mix("cold"), c, 2**33 + 1, 51,
+                                 151936)
+    warm = traffic.make_workload(traffic.load_mix("sessions"), c, 2**33 + 1,
+                                 51, 151936)
+    assert [(len(r.tokens), r.max_new, r.due_s) for r in cold.requests] == \
+        [(len(r.tokens), r.max_new, r.due_s) for r in warm.requests]
+    # each request's blocks (the last one cut to the prompt) are its own
+    owner = {}
+    for r in cold.requests:
+        for i in range(0, len(r.tokens), 512):
+            b = tuple(r.tokens[i:i + 512])
+            assert owner.setdefault(b, r.req_id) == r.req_id
+    assert cold.hit_depths == {S: [0] for S in cold.prompt_lengths}
+    assert any(d > 0 for d in sum(warm.hit_depths.values(), []))
 
 
 def test_chat_prompts_and_session_bound():

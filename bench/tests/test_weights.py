@@ -1,22 +1,22 @@
 """The weights made all at once for the program equal those the
 reference makes one layer at a time."""
-import jax
+from pathlib import Path
+
 import numpy as np
 
 from bench import reference, weights
 from bench.model import load_config
-from pathlib import Path
 
 TINY = Path(__file__).resolve().parent / "data" / "tiny.json"
 
 
 def test_stacked_equals_per_layer_and_padding_is_zero():
-    s, _ = load_config("tiny", path=TINY)
+    dense, s = load_config("tiny", path=TINY)
     rows = reference.padded_rows(s.vocab_size)
-    p = weights.program_params(s, 2**32 + 3, rows)
+    p = dense.program_params(s, 2**32 + 3, rows)
     root = weights.root_key(2**32 + 3)
     for layer in range(s.n_layers):
-        t = reference._layer_weights(root, jax.numpy.uint32(layer), shape=s)
+        t = dense.layer_weights(s, root, layer)
         np.testing.assert_array_equal(np.asarray(p["attn"]["wq"][layer], np.float32),
                                       np.asarray(t["wq"], np.float32))
         np.testing.assert_array_equal(np.asarray(p["mlp"]["w2"][layer], np.float32),
@@ -30,7 +30,7 @@ def test_stacked_equals_per_layer_and_padding_is_zero():
 
 
 def test_seeds_differ():
-    s, _ = load_config("tiny", path=TINY)
-    a = weights.program_params(s, 1, 512)["attn"]["wk"]
-    b = weights.program_params(s, 2, 512)["attn"]["wk"]
+    dense, s = load_config("tiny", path=TINY)
+    a = dense.program_params(s, 1, 512)["attn"]["wk"]
+    b = dense.program_params(s, 2, 512)["attn"]["wk"]
     assert not np.array_equal(np.asarray(a), np.asarray(b))

@@ -21,7 +21,10 @@ Prompts are realised from hash chains of 512-token blocks as in the
 Mooncake open trace: equal block ids give equal blocks and equal
 prefixes, so prefix reuse is the trace's (the session and system-prompt
 structure is copied from ``repro.core.trace.generate_trace``, the block
-realisation from ``repro.data.pipeline.realize_request_tokens``).
+realisation from ``repro.data.pipeline.realize_request_tokens``). An open
+mix with ``"fresh_blocks": true`` draws every request's chain from new
+block ids: the same schedule, and no system prompt, turn or document
+shared between requests.
 """
 from __future__ import annotations
 
@@ -182,12 +185,15 @@ def _open_workload(mix: dict, cell: dict, seed: int, seconds: float,
         return ids
 
     sys_ids = [new_ids(b) for b in sys_blocks]
+    fresh = mix.get("fresh_blocks", False)
     reqs, full = [], []
     for slot, si in enumerate(order):
         kind, s, shape = sessions[si]
         t = starts[slot]
         chain = list(sys_ids[s]) if s is not None else []
         for prompt, out, gap in shape:
+            if fresh:
+                chain = []
             if len(chain) * BLOCK < prompt:
                 chain = chain + new_ids(-(-prompt // BLOCK) - len(chain))
             if t < seconds:
