@@ -14,6 +14,7 @@ Key derived quantities:
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -109,6 +110,38 @@ class MoEConfig:
     parallelism: str = "ep"
     # apply MoE every k-th layer (1 = all layers); others use dense MLP
     every: int = 1
+    # --- DeepSeek-V3 expert layer of latent-attention stacks
+    # (``models/layers.py`` ``expert_share_block``: sigmoid scores, picks
+    # on score + a per-expert selection bias, gates the picked scores
+    # renormalised × ``routed_scale``) ---
+    routed_scale: float = 1.0
+    d_ff_shared: int = 0        # width of the shared SwiGLU every token takes
+    first_dense: int = 0        # leading layers with a dense SwiGLU (d_ff)
+    # the experts this chip holds, [expert_first, expert_first + n_held);
+    # the router keeps all ``n_experts`` (0 = every expert is held)
+    expert_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): per token the cache
+    holds the RMS-normed latent ``c_kv`` (``kv_lora_rank`` wide) and one
+    roped key ``k_pe`` (``qk_rope_head_dim``) shared by every head;
+    ``kv_b`` expands ``c_kv`` to each head's ``qk_nope_head_dim`` key part
+    and ``v_head_dim`` value."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -140,6 +173,7 @@ class ModelConfig:
     head_dim: int = 128
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None   # latent attention in every layer
     # --- attention flavour ---
     qk_norm: bool = False
     attn_bias: bool = False
@@ -186,6 +220,23 @@ class ModelConfig:
         if self.attn_every:
             return self.n_layers // self.attn_every
         return self.n_layers
+
+    @property
+    def kv_widths(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """Per-token trailing shapes of the two cached tensors, the one
+        place every KV store reads them from: (KV, Dh) K and V, or MLA's
+        latent pair, ``c_kv`` (kv_lora_rank,) and ``k_pe``
+        (qk_rope_head_dim,), with no head axis."""
+        if self.mla is not None:
+            return (self.mla.kv_lora_rank,), (self.mla.qk_rope_head_dim,)
+        kv = (self.n_kv_heads, self.head_dim)
+        return kv, kv
+
+    @property
+    def kv_token_bytes(self) -> int:
+        """bf16 bytes of one token's cache over every attention layer."""
+        k, v = self.kv_widths
+        return 2 * self.attention_layers * (math.prod(k) + math.prod(v))
 
     def param_count(self) -> int:
         """Approximate total parameter count (used for roofline MODEL_FLOPS)."""

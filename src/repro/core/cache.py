@@ -321,8 +321,7 @@ def cache_hit_analysis(requests, policy: str, capacity: Optional[int]) -> float:
 
 def kv_block_bytes(cfg, block_tokens: int = 512) -> int:
     """Bytes of one 512-token KVCache block for a model config (bf16)."""
-    return 2 * cfg.attention_layers * block_tokens * cfg.n_kv_heads \
-        * cfg.head_dim * 2
+    return block_tokens * cfg.kv_token_bytes
 
 
 def ssm_state_bytes(cfg) -> int:

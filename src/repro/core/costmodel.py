@@ -54,8 +54,7 @@ class CostModel:
         self.cfg = cfg
         self.inst = inst
         self.n_params_active = cfg.active_param_count()
-        self.kv_token_bytes = (2 * cfg.attention_layers * cfg.n_kv_heads
-                               * cfg.head_dim * 2)  # bf16 K+V per token
+        self.kv_token_bytes = cfg.kv_token_bytes  # bf16 cache per token
         self.weight_bytes = self.n_params_active * 2  # bf16
         self._ssd_s_per_token = None   # measured override (calibrate_ssd_read)
 

@@ -644,3 +644,155 @@ def moe_block(x, p, cfg: ModelConfig, dist: Dist):
         check_vma=False)
     y, aux = fn(h.reshape(T_total, D), p["router"], p["w1"], p["w2"], p["w3"])
     return y.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+
+def _mla_project(h, p, cfg: ModelConfig, positions):
+    """h (B, S, D) normed, positions (B, S) -> q_nope (B, S, H, nope),
+    q_pe (B, S, H, rope) roped, c_kv (B, S, rank) normed, k_pe (B, S,
+    rope) roped: the per-token latent pair the cache holds."""
+    m = cfg.mla
+    B, S, _ = h.shape
+    q = (h @ p["wq"]).reshape(B, S, cfg.n_heads, m.qk_head_dim)
+    q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+    kv = h @ p["wkv_a"]
+    c_kv = rms_norm(kv[..., :m.kv_lora_rank], p["kv_ln"], cfg.norm_eps)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    k_pe = apply_rope(kv[..., None, m.kv_lora_rank:], positions,
+                      cfg.rope_theta)[:, :, 0]
+    return q_nope, q_pe, c_kv, k_pe
+
+
+def mla_attention_block(x, p, cfg: ModelConfig, *, positions, cache, layer):
+    """MLA in the expanded form over a dense latent arena (prefill, chunk
+    extension, the dense decode oracle): norm → q and the latent pair of
+    the new tokens → write the pair into layer ``layer`` of ``cache`` at
+    ``positions`` → expand every cached ``c_kv`` through ``kv_b`` to
+    per-head keys and values → causal attention (a query sees the cached
+    positions up to its own) → ``o_proj``.
+
+    x: (B, S, D); positions: (B, S) absolute; cache: (c_kv, k_pe) arenas
+    (L, B, S_cache, rank) and (L, B, S_cache, rope), threaded through the
+    layers. Returns (y, cache)."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    H = cfg.n_heads
+    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    q_nope, q_pe, c_new, pe_new = _mla_project(h, p, cfg, positions)
+    c_all, pe_all = cache
+    rows = jnp.arange(B)[:, None]
+    c_all = c_all.at[layer, rows, positions].set(c_new.astype(c_all.dtype))
+    pe_all = pe_all.at[layer, rows, positions].set(
+        pe_new.astype(pe_all.dtype))
+    c_kv, k_pe = c_all[layer], pe_all[layer]
+    Sk = c_kv.shape[1]
+    kv = (c_kv @ p["wkv_b"]).reshape(B, Sk, H,
+                                     m.qk_nope_head_dim + m.v_head_dim)
+    k_nope, v = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
+    logits = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe,
+                           preferred_element_type=jnp.float32))
+    logits = logits * m.qk_head_dim ** -0.5
+    mask = jnp.arange(Sk)[None, None, :] <= positions[:, :, None]
+    logits = jnp.where(mask[:, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+    return o.reshape(B, S, H * m.v_head_dim) @ p["wo"], (c_all, pe_all)
+
+
+def paged_mla_block(x, p, cfg: ModelConfig, *, c_pages, pe_pages, layer,
+                    block_table, seq_lens):
+    """MLA decode over the latent page slabs, absorbed: ``q_nope·W_UK``
+    puts each head's query into the ``c_kv`` space, scores are taken
+    against the cached ``c_kv`` and ``k_pe``, the context stays latent,
+    then ``W_UV`` and ``o_proj``. ``kv_b`` is never applied to the cache.
+
+    x: (B, 1, D); c_pages (L, P, page, rank), pe_pages (L, P, page, rope):
+    the stacked slabs, threaded whole through the layers so that only the
+    B new rows of layer ``layer`` are written; block_table (B, max_pages);
+    seq_lens (B,) tokens already written per slot (the new token lands
+    there; inactive slots point at the null page). Returns (y, (c_pages,
+    pe_pages))."""
+    m = cfg.mla
+    B = x.shape[0]
+    H = cfg.n_heads
+    pos = jnp.asarray(seq_lens)
+    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    q_nope, q_pe, c_new, pe_new = _mla_project(h, p, cfg, pos[:, None])
+    pt = c_pages.shape[2]
+    n = block_table.shape[1]
+    pids = block_table[jnp.arange(B), jnp.clip(pos // pt, 0, n - 1)]
+    offs = pos % pt
+    c_pages = c_pages.at[layer, pids, offs].set(
+        c_new[:, 0].astype(c_pages.dtype))
+    pe_pages = pe_pages.at[layer, pids, offs].set(
+        pe_new[:, 0].astype(pe_pages.dtype))
+    c_kv = c_pages[layer][block_table].reshape(B, n * pt, m.kv_lora_rank)
+    k_pe = pe_pages[layer][block_table].reshape(B, n * pt,
+                                                m.qk_rope_head_dim)
+    w_b = p["wkv_b"].reshape(m.kv_lora_rank, H,
+                             m.qk_nope_head_dim + m.v_head_dim)
+    w_uk, w_uv = jnp.split(w_b, [m.qk_nope_head_dim], axis=-1)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk)
+    logits = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0], k_pe,
+                           preferred_element_type=jnp.float32))
+    logits = logits * m.qk_head_dim ** -0.5
+    valid = jnp.arange(n * pt)[None, :] <= pos[:, None]
+    logits = jnp.where(valid[:, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    ctx = jnp.einsum("bhs,bsc->bhc", probs.astype(c_kv.dtype), c_kv)
+    o = jnp.einsum("bhc,chv->bhv", ctx, w_uv).reshape(B, 1, -1)
+    return o @ p["wo"], (c_pages, pe_pages)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 expert layer, as one chip's share of an expert-parallel group
+# ---------------------------------------------------------------------------
+
+def _swiglu(h, w1, w2, w3):
+    return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+
+
+def expert_share_block(x, p, cfg: ModelConfig):
+    """The MoE FFN that this chip holds a share of: the router scores all
+    ``n_experts`` in float32 from the bf16 normed hidden state
+    (``sigmoid``), picks ``top_k`` on score + the selection bias
+    (``noaux_tc`` with one group), and gates by the picked scores
+    renormalised × ``routed_scale``; of those picks only the held experts
+    [``expert_first``, + ``held``) are computed, for the tokens routed to
+    them, and the shared expert is added for every token. What the absent
+    experts would add is left out; on one chip there is no exchange.
+
+    Every held expert runs over every token with its combine weight (0
+    where the token did not pick it), so no token is dropped and the
+    shapes are static.
+
+    x: (B, S, D); p: ``ln``, ``router`` (D, E), ``bias`` (E,) float32,
+    ``w1``/``w3`` (held, D, F), ``w2`` (held, F, D) and ``shared``
+    (``w1``, ``w2``, ``w3``). Returns (y, picked): picked (B·S, held)
+    bool, whether each token routed to each held expert."""
+    moe = cfg.moe
+    B, S, D = x.shape
+    h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(B * S, D)
+    logits = jnp.dot(h.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["bias"], moe.top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / gates.sum(-1, keepdims=True) * moe.routed_scale
+    # (T, k, held): a pick of an expert held elsewhere matches no column
+    hit = jax.nn.one_hot(idx - moe.expert_first, moe.held, dtype=jnp.float32)
+    combine = jnp.einsum("tk,tke->te", gates, hit)
+    up = jax.nn.silu(jnp.einsum("td,edf->etf", h, p["w1"])) \
+        * jnp.einsum("td,edf->etf", h, p["w3"])
+    out = jnp.einsum("etf,efd->etd", up, p["w2"])
+    y = jnp.einsum("te,etd->td", combine, out.astype(jnp.float32))
+    sh = p["shared"]
+    y = y + _swiglu(h, sh["w1"], sh["w2"], sh["w3"]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, D), hit.sum(1) > 0

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (DTYPE, Dist, NO_DIST, attention_block,
+                                 expert_share_block, mla_attention_block,
                                  mlp_block, moe_block, rms_norm)
 from repro.models.mamba import MambaState, mamba_block
 
@@ -109,6 +110,43 @@ def _init_moe(key, cfg: ModelConfig, L: int) -> dict:
     )
 
 
+def _init_mla(key, cfg: ModelConfig, L: int) -> dict:
+    D, H, m = cfg.d_model, cfg.n_heads, cfg.mla
+    ks = jax.random.split(key, 4)
+    s_in = (2.0 / (D + H * m.qk_head_dim)) ** 0.5
+    return dict(
+        ln=jnp.ones((L, D), DTYPE),
+        wq=_norm(ks[0], (L, D, H * m.qk_head_dim), s_in),
+        wkv_a=_norm(ks[1], (L, D, m.kv_lora_rank + m.qk_rope_head_dim),
+                    s_in),
+        kv_ln=jnp.ones((L, m.kv_lora_rank), DTYPE),
+        wkv_b=_norm(ks[2], (L, m.kv_lora_rank,
+                            H * (m.qk_nope_head_dim + m.v_head_dim)),
+                    m.kv_lora_rank ** -0.5),
+        wo=_norm(ks[3], (L, H * m.v_head_dim, D), s_in),
+    )
+
+
+def _init_expert_share(key, cfg: ModelConfig, L: int) -> dict:
+    """The held experts, the full-width router and its selection bias,
+    and the shared expert (``expert_share_block``)."""
+    D, moe = cfg.d_model, cfg.moe
+    E, F, Fs = moe.held, moe.d_ff_expert, moe.d_ff_shared
+    ks = jax.random.split(key, 7)
+    s, ss = (2.0 / (D + F)) ** 0.5, (2.0 / (D + Fs)) ** 0.5
+    return dict(
+        ln=jnp.ones((L, D), DTYPE),
+        router=_norm(ks[0], (L, D, moe.n_experts), D ** -0.5),
+        bias=jnp.zeros((L, moe.n_experts), jnp.float32),
+        w1=_norm(ks[1], (L, E, D, F), s),
+        w2=_norm(ks[2], (L, E, F, D), s),
+        w3=_norm(ks[3], (L, E, D, F), s),
+        shared=dict(w1=_norm(ks[4], (L, D, Fs), ss),
+                    w2=_norm(ks[5], (L, Fs, D), ss),
+                    w3=_norm(ks[6], (L, D, Fs), ss)),
+    )
+
+
 def _init_mamba(key, cfg: ModelConfig, L: int) -> dict:
     s_cfg = cfg.ssm
     D = cfg.d_model
@@ -142,7 +180,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     if not cfg.tie_embeddings:
         params["lm_head"] = _norm(keys[1], (D, V), D ** -0.5)
 
-    if cfg.kind == "ssm":
+    if cfg.mla is not None:
+        # leading dense layers, then a uniform stack of expert layers
+        n0 = cfg.moe.first_dense
+        params["dense"] = dict(attn=_init_mla(keys[5], cfg, n0),
+                               mlp=_init_mlp(keys[6], cfg, n0))
+        params["attn"] = _init_mla(keys[2], cfg, L - n0)
+        params["moe"] = _init_expert_share(keys[4], cfg, L - n0)
+    elif cfg.kind == "ssm":
         params["mamba"] = _init_mamba(keys[2], cfg, L)
     elif cfg.attn_every:  # hybrid (jamba): period scan stacks
         n_per = L // cfg.attn_every
@@ -194,8 +239,9 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, *,
     kv = None
     if cfg.attention_layers:
         La = cfg.attention_layers
-        kv = KVCache(k=jnp.zeros((La, batch, S, KV, Dh), DTYPE),
-                     v=jnp.zeros((La, batch, S, KV, Dh), DTYPE))
+        wk, wv = cfg.kv_widths
+        kv = KVCache(k=jnp.zeros((La, batch, S) + wk, DTYPE),
+                     v=jnp.zeros((La, batch, S) + wv, DTYPE))
     ssm = None
     if cfg.ssm is not None and cfg.kind in ("ssm", "hybrid"):
         s_cfg = cfg.ssm
@@ -460,8 +506,65 @@ def _logits_at(params, h_last, cfg: ModelConfig):
     return (h_last @ w).astype(jnp.float32)
 
 
+def _mla_stack(params, x, cfg: ModelConfig, attend, kv):
+    """The latent-attention model's layers: the leading dense layers, then
+    one scan over the uniform expert layers. ``attend(x, p, layer, kv) ->
+    (y, kv)`` is one layer's attention, told its index into the stacked
+    caches ``kv``, which every layer threads (so a layer writes only its
+    own new rows). Returns (x, kv, picked): picked (L - first_dense, T,
+    held) bool, each token's routes to the held experts."""
+    n0 = cfg.moe.first_dense
+    for layer in range(n0):
+        p = jax.tree.map(lambda t, i=layer: t[i], params["dense"])
+        with jax.named_scope("mla"):
+            y, kv = attend(x, p["attn"], layer, kv)
+        x = x + y
+        x = x + mlp_block(x, p["mlp"], cfg)
+
+    def block(carry, xs_):
+        x, kv = carry
+        p_a, p_m, layer = xs_
+        with jax.named_scope("mla"):
+            y, kv = attend(x, p_a, layer, kv)
+        x = x + y
+        with jax.named_scope("moe"):
+            y, picked = expert_share_block(x, p_m, cfg)
+        return (x + y, kv), picked
+
+    (x, kv), picked = jax.lax.scan(
+        block, (x, kv),
+        (params["attn"], params["moe"], jnp.arange(n0, cfg.n_layers)))
+    return x, kv, picked
+
+
+def _run_mla(params, x, cfg: ModelConfig, caches: Optional[Caches]):
+    """``_run_stack`` for latent attention: extend ``caches`` (dense
+    latent arenas) at their length, or, with none (prefill, train), fill
+    a fresh arena of the sequence's length. The aux output counts the
+    token-expert pairs routed to the held experts."""
+    B, S, _ = x.shape
+    if caches is None:
+        caches = init_caches(cfg, B, S)
+    pos = jnp.asarray(caches.length)
+    positions = jnp.broadcast_to(
+        (pos[:, None] if pos.ndim == 1 else pos)
+        + jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+
+    def attend(x, p, layer, kv):
+        return mla_attention_block(x, p, cfg, positions=positions, cache=kv,
+                                   layer=layer)
+
+    x, kv, picked = _mla_stack(params, x, cfg, attend, tuple(caches.kv))
+    return x, picked.sum(dtype=jnp.int32), (KVCache(*kv), None)
+
+
 def _run_stack(params, x, cfg, dist, *, mode, caches, q_offset, ring,
                window_override):
+    if cfg.mla is not None:
+        if q_offset or ring or window_override or dist.active:
+            raise ValueError("latent attention runs on one device from "
+                             "position 0 or a cache's length, unwindowed")
+        return _run_mla(params, x, cfg, caches)
     if cfg.attn_every:
         h, aux, kv_stack, st_stack = _hybrid_stack(
             params, x, cfg, dist, mode=mode, caches=caches, q_offset=q_offset,
@@ -547,9 +650,12 @@ def prefill(params, tokens, cfg: ModelConfig, dist: Dist = NO_DIST, *,
 
 
 def decode_step(params, tokens, caches: Caches, cfg: ModelConfig,
-                dist: Dist = NO_DIST, *, ring=False, window_override=None):
+                dist: Dist = NO_DIST, *, ring=False, window_override=None,
+                held_pairs: bool = False):
     """Decode (S small, usually 1) against preallocated caches.
-    Returns (logits (B, S, V), updated caches)."""
+    Returns (logits (B, S, V), updated caches), and with ``held_pairs``
+    (latent-attention expert models) the token-expert pairs the step
+    routed to the held experts as a third output."""
     x = _embed(params, tokens, cfg)
     x = dist.constrain(x, dist.residual_spec(x.shape[1]))
     h, aux, out = _run_stack(params, x, cfg, dist, mode="decode",
@@ -562,6 +668,8 @@ def decode_step(params, tokens, caches: Caches, cfg: ModelConfig,
                  ssm=ssm if ssm is not None else caches.ssm,
                  enc_kv=caches.enc_kv,
                  length=caches.length + tokens.shape[1])
+    if held_pairs:
+        return logits, new, aux
     return logits, new
 
 
@@ -580,11 +688,19 @@ def decode_step_paged(params, tokens, k_pages, v_pages, block_table,
     engine (growth, COW, slot free), not traced state.
 
     Supports uniform attention stacks only (the engine's serving archs);
-    hybrid/SSM/encoder models keep the dense path.
+    hybrid/SSM/encoder models keep the dense path. Latent attention goes
+    to ``decode_step_paged_mla``, which returns a fourth output.
     """
     from repro.models.layers import paged_attention_block
     assert cfg.attention_layers == cfg.n_layers and not cfg.encoder_layers, \
         "paged decode supports uniform attention stacks"
+    if cfg.mla is not None:
+        if use_pallas or shard is not None or window_override:
+            raise ValueError(
+                "latent-attention paged decode runs the absorbed jnp form "
+                "on one device: no Pallas kernel, shard or window")
+        return decode_step_paged_mla(params, tokens, k_pages, v_pages,
+                                     block_table, seq_lens, cfg)
     use_moe = cfg.moe is not None and cfg.moe.every == 1
     x = _embed(params, tokens, cfg)
     x = dist.constrain(x, dist.residual_spec(x.shape[1]))
@@ -611,6 +727,31 @@ def decode_step_paged(params, tokens, k_pages, v_pages, block_table,
     return logits, kps, vps
 
 
+def decode_step_paged_mla(params, tokens, c_pages, pe_pages, block_table,
+                          seq_lens, cfg: ModelConfig):
+    """``decode_step_paged`` for latent attention: ``paged_mla_block``
+    (absorbed) over the ``c_kv`` and ``k_pe`` slabs (L, P, page, rank) and
+    (L, P, page, rope), which ride the layers whole so that each layer
+    writes its B new rows in place (donate them to the jitted step).
+    Returns (logits (B, 1, V), c_pages, pe_pages, touched): touched
+    counts, over the expert layers, the held experts that got at least
+    one token of an active slot (``seq_lens`` > 0)."""
+    from repro.models.layers import paged_mla_block
+    x = _embed(params, tokens, cfg)
+
+    def attend(x, p, layer, kv):
+        return paged_mla_block(x, p, cfg, c_pages=kv[0], pe_pages=kv[1],
+                               layer=layer, block_table=block_table,
+                               seq_lens=seq_lens)
+
+    x, (c_pages, pe_pages), picked = _mla_stack(params, x, cfg, attend,
+                                                (c_pages, pe_pages))
+    active = (jnp.asarray(seq_lens) > 0)[None, :, None]
+    touched = jnp.any(picked & active, axis=1).sum(dtype=jnp.int32)
+    h = rms_norm(x, params["final_ln"], cfg.norm_eps)
+    return _logits_at(params, h, cfg), c_pages, pe_pages, touched
+
+
 def paged_shard_reason(cfg: ModelConfig, model_shards: int,
                        data_shards: int = 1) -> str:
     """Why the sharded paged decode step can NOT cover ``cfg`` on a
@@ -622,6 +763,9 @@ def paged_shard_reason(cfg: ModelConfig, model_shards: int,
     from repro.models.layers import GROUPED_ATTN
     if not paged_supported_cfg(cfg):
         return "paged decode covers uniform attention stacks only"
+    if cfg.mla is not None:
+        return ("latent attention keeps one c_kv/k_pe slab pair with no "
+                "head axis to stripe; its paged step runs on one device")
     if cfg.moe is not None and cfg.moe.every == 1:
         return ("MoE layers route through their own shard_map dispatch; "
                 "the sharded paged step covers dense-MLP stacks")

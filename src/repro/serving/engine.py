@@ -495,7 +495,7 @@ class HostKVPool:
 
     # ---- bytes ---------------------------------------------------------
     def get(self, hash_ids: list[int]):
-        """Stack blocks → (L, n*512, KV, Dh) k and v."""
+        """Stack blocks → (L, n*512, ...) k and v (``cfg.kv_widths``)."""
         ks, vs = [], []
         for h in hash_ids:
             kv = self.data.get(h)
@@ -509,7 +509,9 @@ class HostKVPool:
 
     def put(self, hash_ids: list[int], k: np.ndarray, v: np.ndarray,
             start_pos: int = 0) -> None:
-        """k/v: (L, n*512, KV, Dh) covering ``hash_ids`` in order."""
+        """k/v: (L, n*512, ...) covering ``hash_ids`` in order, each with
+        its per-token width (``cfg.kv_widths``: (KV, Dh), or a latent
+        pair's ``c_kv`` and ``k_pe``)."""
         evicted = self.meta.insert(hash_ids, start_pos=start_pos)
         for e in evicted:
             self.data.pop(e, None)      # file-backed: on_drop already freed
@@ -670,10 +672,13 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 @partial(jax.jit, static_argnums=2)
 def _device_tokens(x, lo, n: int):
-    """Tokens [lo, lo + n) of device KV, (L, S, KV, Dh) or a B=1 dense
-    cache (L, 1, S, KV, Dh), as (L, n, KV, Dh). ``lo`` is traced: one
-    program per (S, n) serves a block wherever it lies in the prompt."""
-    x = x.reshape((x.shape[0], -1) + x.shape[-2:])
+    """Tokens [lo, lo + n) of device KV, (L, S, ...) or a B=1 dense cache
+    (L, 1, S, ...), told apart by its unit batch axis (a one-token run in
+    the first layout reads as the second), as (L, n, ...) with the
+    per-token width of ``cfg.kv_widths``. ``lo`` is traced: one program
+    per (S, n) serves a block wherever it lies in the prompt."""
+    if x.shape[1] == 1:
+        x = x.reshape(x.shape[:1] + x.shape[2:])
     return jax.lax.dynamic_slice_in_dim(x, lo, n, axis=1)
 
 
@@ -849,14 +854,21 @@ class PrefillWorker:
         self.ssd_mode = ssd_mode
         self.page_pool = page_pool      # shared DevicePagePool (paged handoff)
         self.hasher = PrefixHasher()
+        # a latent-attention expert model also counts, on the device, the
+        # token-expert pairs each chunk routes to the experts held here
+        share = cfg.mla is not None
         self._extend = jax.jit(
-            lambda p, t, c: decode_step(p, t, c, cfg))
+            lambda p, t, c: decode_step(p, t, c, cfg, held_pairs=share))
         self.counters = dict(reused_blocks=0, computed_tokens=0, requests=0,
                              ssd_loaded_blocks=0, overlapped_requests=0,
                              fallback_blocks=0, peer_blocks=0,
                              skipped_blocks=0, chunks=0, h2d_bytes=0,
                              d2h_bytes=0, put_bytes=0, stage_bytes=0,
                              device_stages=0, host_stages=0)
+        self._pairs_held = None
+        if share:
+            self.counters["moe_pairs"] = 0
+            self._pairs_held = jnp.zeros((), jnp.int32)
         # serving-loop hook: called with the page count a stage would pin;
         # returning False skips staging (the join stages later) so staged
         # results can't eat the decode batch's reserved growth pages
@@ -882,7 +894,13 @@ class PrefillWorker:
         """One timed device chunk (the resumable unit)."""
         with span("prefill.compute", lo=lo, hi=hi, **info):
             t0 = time.monotonic()
-            logits, caches = self._extend(self.params, t[:, lo:hi], caches)
+            logits, caches, *held = self._extend(self.params, t[:, lo:hi],
+                                                 caches)
+            if held:
+                moe = self.cfg.moe
+                self._pairs_held = self._pairs_held + held[0]
+                self.counters["moe_pairs"] += (hi - lo) * moe.top_k * (
+                    self.cfg.n_layers - moe.first_dense)
             jax.block_until_ready(logits)
             self._note_compute(hi - lo, time.monotonic() - t0)
         self.counters["chunks"] += 1
@@ -938,8 +956,14 @@ class PrefillWorker:
         from the dense cache, ``put_bytes`` stored into the host pool,
         ``stage_bytes`` written into device pages), and how each finished
         prefill handed its KV on (``device_stages`` into pages from the
-        device, ``host_stages`` read back whole for a later join)."""
-        return dict(self.counters)
+        device, ``host_stages`` read back whole for a later join). An
+        expert model's share adds ``moe_pairs``, the token-expert pairs
+        its chunks routed, and ``moe_pairs_held``, those that fell to the
+        experts held here (read from the device now)."""
+        out = dict(self.counters)
+        if self._pairs_held is not None:
+            out["moe_pairs_held"] = int(self._pairs_held)
+        return out
 
     def _finish(self, logits, kv: KVCache, hash_ids, runs, S: int,
                 info: dict) -> dict:
@@ -1291,6 +1315,12 @@ class DecodeWorker:
         self.counters = dict(zero_copy_joins=0, staged_joins=0, steps=0,
                              preemptions=0, resumed_joins=0,
                              bucket_substeps=0)
+        # a latent-attention expert model's paged step also returns how
+        # many held experts its tokens touched: summed on the device
+        self._touched = None
+        if substrate == "paged" and cfg.mla is not None:
+            self.counters["moe_held"] = 0
+            self._touched = jnp.zeros((), jnp.int32)
         if substrate == "paged":
             from repro.serving.paged_cache import DevicePagePool
             if page_pool is not None:
@@ -1336,9 +1366,11 @@ class DecodeWorker:
             self.n_pages_slot = np.zeros(max_batch, np.int32)
             self.caches = None
             if mesh is None:
+                # the latent step writes its rows into the slabs in place
                 self._step_paged = jax.jit(
                     lambda p, t, kp, vp, tbl, lens: decode_step_paged(
-                        p, t, kp, vp, tbl, lens, cfg, use_pallas=use_pallas))
+                        p, t, kp, vp, tbl, lens, cfg, use_pallas=use_pallas),
+                    donate_argnums=(2, 3) if cfg.mla is not None else ())
             else:
                 self._step_paged = jax.jit(
                     lambda p, t, kp, vp, tbl, lens:
@@ -1582,7 +1614,19 @@ class DecodeWorker:
         out = dict(self.counters)
         out["active_slots"] = self.n_active
         out["reserved_growth_pages"] = self.reserved_growth_pages()
+        if self._touched is not None:
+            out["moe_touched"] = int(self._touched)
         return out
+
+    def _note_touched(self, touched: list) -> None:
+        """Sum a paged step's count of held experts touched (``moe_touched``,
+        on the device) beside what it could have touched (``moe_held``:
+        every expert layer's held experts)."""
+        if touched:
+            moe = self.cfg.moe
+            self._touched = self._touched + touched[0]
+            self.counters["moe_held"] += \
+                (self.cfg.n_layers - moe.first_dense) * moe.held
 
     def _prepare_writes(self, active: list[int]) -> None:
         """Host-side bookkeeping before a step: give every active slot an
@@ -1628,9 +1672,10 @@ class DecodeWorker:
             # no host buffer aliases into the async step)
             tbl = jnp.asarray(self.block_table[:, :width] % pp.bank_pages)
         lens = jnp.asarray(self.seq_lens.copy())
-        logits, kp, vp = self._step_paged(
+        logits, kp, vp, *touched = self._step_paged(
             self.params, self.tokens, pp.k_pages, pp.v_pages, tbl, lens)
         pp.k_pages, pp.v_pages = kp, vp
+        self._note_touched(touched)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
     def _step_bucketed(self, active: list[int]) -> jax.Array:
@@ -1667,9 +1712,10 @@ class DecodeWorker:
             tbl[:len(rows)] = self.block_table[rows][:, :w]
             lens = np.zeros(nr, np.int32)
             lens[:len(rows)] = self.seq_lens[rows]
-            logits, kp, vp = self._step_paged(
+            logits, kp, vp, *touched = self._step_paged(
                 self.params, jnp.asarray(toks), kp, vp,
                 jnp.asarray(tbl), jnp.asarray(lens))
+            self._note_touched(touched)
             sub = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             for j, i in enumerate(rows):
                 nxt[i] = int(sub[j])

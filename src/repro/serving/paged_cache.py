@@ -4,7 +4,9 @@ these pages, step 2 stores new pages back).
 
 Layout (per attention layer stacked on a leading axis):
 
-    k_pages, v_pages : (L, n_pages, page_tokens, KV, Dh)
+    k_pages, v_pages : (L, n_pages, page_tokens) + ``cfg.kv_widths``:
+                       (KV, Dh) each, or latent attention's c_kv (rank,)
+                       and k_pe (rope,) with no head axis
     block_table      : (B, max_pages_per_seq) int32 — page id per slot
     seq_lens         : (B,) int32
 
@@ -68,11 +70,11 @@ def init_paged_cache(cfg: ModelConfig, *, batch: int, n_pages: int,
                      page_tokens: int = 64,
                      max_seq: int = 32768) -> PagedKVCache:
     La = cfg.attention_layers
-    KV, Dh = cfg.n_kv_heads, cfg.head_dim
+    wk, wv = cfg.kv_widths
     max_pages = (max_seq + page_tokens - 1) // page_tokens
     return PagedKVCache(
-        k_pages=jnp.zeros((La, n_pages, page_tokens, KV, Dh), DTYPE),
-        v_pages=jnp.zeros((La, n_pages, page_tokens, KV, Dh), DTYPE),
+        k_pages=jnp.zeros((La, n_pages, page_tokens) + wk, DTYPE),
+        v_pages=jnp.zeros((La, n_pages, page_tokens) + wv, DTYPE),
         block_table=jnp.zeros((batch, max_pages), jnp.int32),
         seq_lens=jnp.zeros((batch,), jnp.int32),
         page_tokens=page_tokens,
@@ -171,10 +173,10 @@ def write_kv(cache: PagedKVCache, slot: int, start: int,
         src = slice(tok - start, tok - start + n)
         k_pages = jax.lax.dynamic_update_slice(
             k_pages, k_new[:, src][:, None],
-            (0, pid, off, 0, 0))
+            (0, pid, off) + (0,) * (k_pages.ndim - 3))
         v_pages = jax.lax.dynamic_update_slice(
             v_pages, v_new[:, src][:, None],
-            (0, pid, off, 0, 0))
+            (0, pid, off) + (0,) * (v_pages.ndim - 3))
         tok += n
     return PagedKVCache(k_pages, v_pages, cache.block_table, cache.seq_lens,
                         pt, cache.free)
@@ -202,8 +204,8 @@ def gather_kv(cache: PagedKVCache, max_tokens: int):
 def page_write(page_tokens: int, *, per_page: bool, sharding=None):
     """The jitted page write behind ``DevicePagePool.write_run``:
     ``(k_pages, v_pages, idx, k, v) -> (k_pages, v_pages)`` with (L, T,
-    KV, Dh) KV written into the pages ``idx`` (a partial tail page
-    zero-padded). Both slabs are donated, so the write happens in place;
+    ...) K and V (each its slab's per-token width) written into the pages
+    ``idx`` (a partial tail page zero-padded). Both slabs are donated, so the write happens in place;
     on a mesh, ``sharding`` is pinned as the slabs' output sharding.
 
     ``per_page``: one ``dynamic_update_slice`` per page, which touches
@@ -212,24 +214,36 @@ def page_write(page_tokens: int, *, per_page: bool, sharding=None):
     minor-most, every page is strided over the whole slab, and one pass
     beats a pass per page. On a TPU v5e: 1.0 against 17.6 ms per
     8-page write into qwen2.5-3b's slabs (pages major), 26 against 333 ms
-    into phi3-mini's (pages minor)."""
+    into phi3-mini's (pages minor). One flag for both slabs, or a (k, v)
+    pair where their layouts differ (a latent pair's 64-wide ``k_pe``
+    slab puts its pages minor-most, its 512-wide ``c_kv`` slab does
+    not)."""
     pt = page_tokens
+    pk, pv = (per_page, per_page) if isinstance(per_page, bool) \
+        else per_page
 
     def write(k_pages, v_pages, idx, k, v):
         n = idx.shape[0]
 
         def paged(x):
             x = x.astype(k_pages.dtype)
-            x = jnp.pad(x, ((0, 0), (0, n * pt - x.shape[1]), (0, 0), (0, 0)))
+            x = jnp.pad(x, ((0, 0), (0, n * pt - x.shape[1]))
+                        + ((0, 0),) * (x.ndim - 2))
             return x.reshape((x.shape[0], n, pt) + x.shape[2:])
 
         k, v = paged(k), paged(v)
-        if not per_page:
-            return k_pages.at[:, idx].set(k), v_pages.at[:, idx].set(v)
+        if not pk:
+            k_pages = k_pages.at[:, idx].set(k)
+        if not pv:
+            v_pages = v_pages.at[:, idx].set(v)
         for i in range(n):
-            at = (0, idx[i], 0, 0, 0)
-            k_pages = jax.lax.dynamic_update_slice(k_pages, k[:, i:i + 1], at)
-            v_pages = jax.lax.dynamic_update_slice(v_pages, v[:, i:i + 1], at)
+            at = (0, idx[i])
+            if pk:
+                k_pages = jax.lax.dynamic_update_slice(
+                    k_pages, k[:, i:i + 1], at + (0,) * (k.ndim - 2))
+            if pv:
+                v_pages = jax.lax.dynamic_update_slice(
+                    v_pages, v[:, i:i + 1], at + (0,) * (v.ndim - 2))
         return k_pages, v_pages
 
     out = None if sharding is None else (sharding, sharding)
@@ -245,8 +259,8 @@ class DevicePagePool:
     HBM that both ``PrefillWorker`` (writes fresh pages, §3 step 2) and
     ``DecodeWorker`` (attends them through block tables, §3 step 4) share.
 
-    * ``k_pages``/``v_pages``: (L, P, page_tokens, KV, Dh); page 0 is the
-      reserved null page (block tables pad with it, reads of it are
+    * ``k_pages``/``v_pages``: (L, P, page_tokens) + ``cfg.kv_widths``;
+      page 0 is the reserved null page (block tables pad with it, reads of it are
       always masked).
     * per-page REFCOUNTS (host side): a page is held by the hash-run
       registry and/or by block-table rows / staged prefill results.
@@ -283,7 +297,8 @@ class DevicePagePool:
             raise ValueError(
                 f"page_tokens={page_tokens} must divide the pool block "
                 f"({BLOCK_TOKENS} tokens) so a block maps to a page run")
-        La, KV, Dh = cfg.attention_layers, cfg.n_kv_heads, cfg.head_dim
+        La, KV = cfg.attention_layers, cfg.n_kv_heads
+        wk, wv = cfg.kv_widths
         self.cfg = cfg
         self.page_tokens = page_tokens
         self.mesh = mesh
@@ -291,17 +306,20 @@ class DevicePagePool:
         if mesh is not None:
             d = int(mesh.shape.get("data", 1))
             m = int(mesh.shape.get("model", 1))
+            if cfg.mla is not None:
+                raise ValueError("a latent page slab has no head axis to "
+                                 "stripe over a mesh")
             if KV % m:
                 raise ValueError(
                     f"{KV} kv heads do not stripe over model={m} shards")
         self.n_banks = d
         self.bank_pages = n_pages       # per-bank budget incl. its null page
         total = d * n_pages
-        shape = (La, total, page_tokens, KV, Dh)
+        shape = (La, total, page_tokens) + wk
         if mesh is None:
             self._sharding = None
             self.k_pages = jnp.zeros(shape, DTYPE)
-            self.v_pages = jnp.zeros(shape, DTYPE)
+            self.v_pages = jnp.zeros((La, total, page_tokens) + wv, DTYPE)
         else:
             from jax.sharding import NamedSharding, PartitionSpec
             self._sharding = NamedSharding(
@@ -312,9 +330,10 @@ class DevicePagePool:
                                           self._sharding)
             self.v_pages = jax.device_put(jnp.zeros(shape, DTYPE),
                                           self._sharding)
-        # the device's layout for the slabs decides how a page is written
-        pages_minor = self.k_pages.format.layout.major_to_minor[-1] == 1
-        self._write = page_write(page_tokens, per_page=not pages_minor,
+        # each slab's device layout decides how a page is written into it
+        per_page = tuple(x.format.layout.major_to_minor[-1] != 1
+                         for x in (self.k_pages, self.v_pages))
+        self._write = page_write(page_tokens, per_page=per_page,
                                  sharding=self._sharding)
         # reentrant: alloc -> eviction -> unregister -> release re-enters
         self._lock = threading.RLock()
@@ -368,7 +387,8 @@ class DevicePagePool:
     @property
     def page_bytes(self) -> int:
         """Bytes of one page's k and v together, over all layers."""
-        return 2 * self.k_pages.nbytes // self.k_pages.shape[1]
+        return (self.k_pages.nbytes + self.v_pages.nbytes) \
+            // self.k_pages.shape[1]
 
     @property
     def pages_written(self) -> int:
@@ -551,7 +571,7 @@ class DevicePagePool:
 
     # ---- device writes -------------------------------------------------
     def write_run(self, pages: list[int], k, v) -> None:
-        """Scatter (L, T, KV, Dh) KV into ``pages`` (T ≤ len(pages)·page).
+        """Scatter (L, T, ...) K and V into ``pages`` (T ≤ len(pages)·page).
         ``k``/``v`` are host arrays or device arrays (a prefill's dense
         cache, sliced). One jitted write (``page_write``) that donates both
         slabs, so the pages are written in place; a partial tail page is
@@ -628,7 +648,7 @@ class DevicePagePool:
 
     # ---- host-side reads (oracle/debug) --------------------------------
     def read_seq(self, pages: list[int], n_tokens: int):
-        """Gather one sequence's contiguous (L, n_tokens, KV, Dh) KV."""
+        """Gather one sequence's contiguous (L, n_tokens, ...) K and V."""
         idx = jnp.asarray(pages, jnp.int32)
         L = self.k_pages.shape[0]
         k = self.k_pages[:, idx]            # (L, n, pt, KV, Dh)

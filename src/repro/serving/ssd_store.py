@@ -47,8 +47,9 @@ _HDR_FIXED = struct.Struct("<4sQI")     # magic, block key, n_layers
 class SSDBlockStore:
     """Slotted, checksummed, file-backed KV block store.
 
-    One block = the per-layer (k, v) arrays of 512 tokens, shape
-    ``(L, T, KV, Dh)`` each. The slot payload is layer-major:
+    One block = the per-layer (k, v) arrays of 512 tokens, ``(L, T) +
+    cfg.kv_widths`` each: ``(KV, Dh)`` both, or a latent ``c_kv`` and
+    ``k_pe`` of different widths. The slot payload is layer-major:
     ``k[0] v[0] k[1] v[1] ...`` so ``read_layer`` is one contiguous read.
     Shapes/dtype are inferred from the first ``put`` and persisted to
     ``meta.json`` next to the data file.
@@ -75,7 +76,8 @@ class SSDBlockStore:
         self._free: list[int] = []              # reusable slot offsets
         #: guarded_by self._lock
         self._staged: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        self._shape: Optional[tuple] = None     # per-array (L, T, KV, Dh)
+        self._shape: Optional[tuple] = None     # k's (L, T, ...)
+        self._v_shape: Optional[tuple] = None   # v's (L, T, ...)
         self._dtype: Optional[np.dtype] = None
         # stats
         self.blocks_written = 0
@@ -102,9 +104,10 @@ class SSDBlockStore:
             with open(meta_path) as f:
                 meta = json.load(f)
             shape, dtype = tuple(meta["shape"]), np.dtype(meta["dtype"])
+            v_shape = tuple(meta.get("v_shape", shape))
         except (ValueError, KeyError, TypeError):
             return                              # torn meta: treat as fresh
-        self._set_shape(np.empty(shape, dtype))
+        self._set_shape(np.empty(shape, dtype), np.empty(v_shape, dtype))
         with self._lock:
             for off in range(0, size - self._slot_size + 1, self._slot_size):
                 raw = self._read_at(off, self._hdr_size)
@@ -118,15 +121,20 @@ class SSDBlockStore:
                     self._free.append(off)
 
     # ---- geometry ------------------------------------------------------
-    def _set_shape(self, k: np.ndarray) -> None:
-        self._shape = tuple(k.shape)
+    def _set_shape(self, k: np.ndarray, v: np.ndarray) -> None:
+        self._shape, self._v_shape = tuple(k.shape), tuple(v.shape)
         self._dtype = k.dtype
-        self._layer_bytes = int(np.prod(self._shape[1:])) * k.dtype.itemsize
+        self._k_bytes = int(np.prod(self._shape[1:])) * k.dtype.itemsize
+        # one layer's k and v together
+        self._layer_bytes = self._k_bytes \
+            + int(np.prod(self._v_shape[1:])) * k.dtype.itemsize
         L = self._shape[0]
         self._hdr_size = _HDR_FIXED.size + 4 * L    # + one CRC32 per layer
-        self._slot_size = self._hdr_size + 2 * L * self._layer_bytes
+        self._slot_size = self._hdr_size + L * self._layer_bytes
         with open(os.path.join(self.dir, "meta.json"), "w") as f:
-            json.dump(dict(shape=list(self._shape), dtype=str(self._dtype),
+            json.dump(dict(shape=list(self._shape),
+                           v_shape=list(self._v_shape),
+                           dtype=str(self._dtype),
                            slot_size=self._slot_size), f)
 
     @property
@@ -136,7 +144,7 @@ class SSDBlockStore:
     @property
     def block_bytes(self) -> int:
         """Payload bytes of one block (k + v, all layers)."""
-        return 2 * self.n_layers * self._layer_bytes if self._shape else 0
+        return self.n_layers * self._layer_bytes if self._shape else 0
 
     @property
     def read_s_ema(self) -> Optional[float]:
@@ -179,7 +187,7 @@ class SSDBlockStore:
         """Stage one block for write-back; flushes a full batch inline."""
         with self._lock:
             if self._shape is None:
-                self._set_shape(np.asarray(k))
+                self._set_shape(np.asarray(k), np.asarray(v))
             self._staged[key] = (np.ascontiguousarray(k),
                                  np.ascontiguousarray(v))
             if len(self._staged) >= self.writeback_batch:
@@ -272,10 +280,11 @@ class SSDBlockStore:
         return off, crcs
 
     def _decode_layer(self, raw: bytes) -> tuple[np.ndarray, np.ndarray]:
-        half = self._layer_bytes
-        shape = self._shape[1:]
-        k = np.frombuffer(raw[:half], dtype=self._dtype).reshape(shape)
-        v = np.frombuffer(raw[half:], dtype=self._dtype).reshape(shape)
+        kb = self._k_bytes
+        k = np.frombuffer(raw[:kb], dtype=self._dtype).reshape(
+            self._shape[1:])
+        v = np.frombuffer(raw[kb:], dtype=self._dtype).reshape(
+            self._v_shape[1:])
         return k, v
 
     def read_layer(self, key: int, layer: int) \
@@ -294,7 +303,7 @@ class SSDBlockStore:
                     self.read_failures += 1
                 return None
             off, crcs = hdr
-            pair = 2 * self._layer_bytes
+            pair = self._layer_bytes
             raw = self._read_at(off + self._hdr_size + layer * pair, pair)
             if raw is None or (zlib.crc32(raw) & 0xFFFFFFFF) != crcs[layer]:
                 self.read_failures += 1
@@ -402,10 +411,9 @@ class PrefetchHandle:
                 self._bufs.pop(key, None)
             elif key not in self.failed:
                 if key not in self._bufs:
-                    k0 = pair[0]
-                    shape = (n_layers,) + k0.shape
-                    self._bufs[key] = (np.empty(shape, k0.dtype),
-                                       np.empty(shape, k0.dtype))
+                    self._bufs[key] = tuple(
+                        np.empty((n_layers,) + a.shape, a.dtype)
+                        for a in pair)
                 self._bufs[key][0][layer] = pair[0]
                 self._bufs[key][1][layer] = pair[1]
                 self._layers_done[key] = self._layers_done.get(key, 0) + 1

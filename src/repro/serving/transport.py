@@ -174,11 +174,13 @@ def _unpack_json(payload: bytes):
 
 def pack_layer(key: int, layer: int, k: np.ndarray, v: np.ndarray) -> bytes:
     """LAYER payload: length-prefixed json meta, then raw k and v bytes
-    (the frame CRC covers all of it — the per-layer integrity check)."""
+    (the frame CRC covers all of it — the per-layer integrity check). k
+    and v may differ in width (a latent ``c_kv`` and ``k_pe``)."""
     kb = np.ascontiguousarray(k).tobytes()
     vb = np.ascontiguousarray(v).tobytes()
     meta = _pack_json(dict(key=int(key), layer=int(layer),
                            shape=list(np.asarray(k).shape),
+                           v_shape=list(np.asarray(v).shape),
                            dtype=str(np.asarray(k).dtype), klen=len(kb)))
     return struct.pack("<I", len(meta)) + meta + kb + vb
 
@@ -193,15 +195,17 @@ def unpack_layer(payload: bytes):
     meta = _unpack_json(payload[4:4 + jlen])
     try:
         shape = tuple(meta["shape"])
+        v_shape = tuple(meta.get("v_shape", shape))
         dtype = np.dtype(meta["dtype"])
         klen = int(meta["klen"])
     except (KeyError, TypeError, ValueError) as e:
         raise TornFrame(f"malformed layer meta: {e}") from None
     body = payload[4 + jlen:]
-    if len(body) != klen + klen or klen != int(np.prod(shape)) * dtype.itemsize:
+    vlen = int(np.prod(v_shape)) * dtype.itemsize
+    if len(body) != klen + vlen or klen != int(np.prod(shape)) * dtype.itemsize:
         raise TornFrame("layer body size disagrees with its meta")
     k = np.frombuffer(body[:klen], dtype=dtype).reshape(shape)
-    v = np.frombuffer(body[klen:], dtype=dtype).reshape(shape)
+    v = np.frombuffer(body[klen:], dtype=dtype).reshape(v_shape)
     return meta, k, v
 
 
