@@ -94,6 +94,11 @@ def _probe_step(dw, reps=8):
         out = fn(*args)
         jax.block_until_ready(out[0])
         best = min(best, time.perf_counter() - t0)
+        if dw.substrate == "paged":
+            # the step donates its slabs: thread them (re-writing the same
+            # rows leaves the pages as they were)
+            args = args[:2] + tuple(out[1:3]) + args[4:]
+            pp.k_pages, pp.v_pages = out[1], out[2]
     return best
 
 

@@ -275,7 +275,7 @@ def mesh_phase(cfg, data: int, model: int, *, n_requests: int = N_REQUESTS,
     sharded = _serve(params, cfg, requests, mesh=mesh)
     pp = sharded["page_pool"]
     slab_devs = {sh.device for sh in pp.k_pages.addressable_shards}
-    L, n_pages, page, KV, Dh = pp.k_pages.shape
+    L, n_pages, page, width = pp.k_pages.shape
     slab_shards = {sh.data.shape for sh in pp.k_pages.addressable_shards}
     # the served steps' logits: one placement, each device its data shard's
     # rows
@@ -303,7 +303,7 @@ def mesh_phase(cfg, data: int, model: int, *, n_requests: int = N_REQUESTS,
             f" GiB of {mem[0].get('bytes_limit', 0) / 2**30:.2f} GiB")
     # each device: its data bank's pages and its model stripe of KV heads;
     # its data shard's rows of the logits
-    want_slab = {(L, n_pages // data, page, KV // model, Dh)}
+    want_slab = {(L, n_pages // data, page, width // model)}
     want_logits = {(MAX_BATCH // data,) + logit_shape[1:]}
     if len(slab_devs) != need or len(logit_devs) != need \
             or slab_shards != want_slab or logit_shards != want_logits:

@@ -22,9 +22,12 @@ from jax import nn
 NEG_INF = -1e30
 
 
-def _linearise(pages, block_table):
-    """(P, page, KV, D) pages + (B, max_pages) table -> (B, S, KV, D)."""
-    g = pages[block_table]              # (B, max_pages, page, KV, D)
+def linearise(pages, block_table, layer=None):
+    """Each slot's pages in table order, as one run of token rows: (P,
+    page, ...) pages, or with ``layer`` the stacked (L, P, page, ...) slab
+    read at that layer, + (B, max_pages) table -> (B, max_pages·page,
+    ...). One gather either way: a layer is never sliced out first."""
+    g = pages[block_table] if layer is None else pages[layer, block_table]
     B = g.shape[0]
     return g.reshape(B, g.shape[1] * g.shape[2], *g.shape[3:])
 
@@ -52,12 +55,17 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, seq_lens, *,
     window:     sliding-window size (0 = full attention)
     Returns (B, H, D) in q.dtype.
     """
-    B, H, D = q.shape
-    KV = k_pages.shape[2]
-    scale = 1.0 / (D ** 0.5)
+    return paged_attention_rows(q, linearise(k_pages, block_table),
+                                linearise(v_pages, block_table), seq_lens,
+                                qh2kv=qh2kv, window=window)
 
-    k = _linearise(k_pages, block_table)              # (B, S, KV, D)
-    v = _linearise(v_pages, block_table)
+
+def paged_attention_rows(q, k, v, seq_lens, *, qh2kv=None, window: int = 0):
+    """``paged_attention_ref`` after the gather: q (B, H, D) against each
+    slot's linearised rows k, v (B, S, KV, D). Returns (B, H, D)."""
+    B, H, D = q.shape
+    KV = k.shape[2]
+    scale = 1.0 / (D ** 0.5)
     S = k.shape[1]
     valid = _valid_mask(S, seq_lens, window)
 

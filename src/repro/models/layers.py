@@ -364,27 +364,32 @@ class PagedShard:
 
 
 def paged_attention_block(x, p, cfg: ModelConfig, dist: Dist, *,
-                          k_pages, v_pages, block_table, seq_lens,
+                          k_pages, v_pages, layer, block_table, seq_lens,
                           use_pallas: bool = False,
                           window_override: Optional[int] = None,
                           shard: Optional[PagedShard] = None):
     """Decode attention sub-block over one layer's PAGED KV store (§3
     step 4 on the block-table substrate): norm → qkv → rope at each
     slot's depth → scatter the new token's K/V into the slot's current
-    tail page → attend through the block table (``paged_decode_attention``
-    — Pallas on TPU, the dense-numerics oracle here).
+    tail page → attend through the block table (the dense-numerics
+    oracle, or with ``use_pallas`` the Pallas kernel).
 
-    x: (B, 1, D); k_pages/v_pages: (P, page, KV, Dh);
-    block_table: (B, max_pages) int32; seq_lens: (B,) tokens already
-    written per slot (the new token lands at that position, exactly like
-    the dense path's ``cache_len``). Returns (y, (k_pages, v_pages)).
+    x: (B, 1, D); k_pages/v_pages: the stacked lane-dense slabs (L, P,
+    page, KV·Dh), threaded whole through the layers so that this layer,
+    ``layer``, writes only its B new rows in place (one scatter at (layer,
+    page, offset)) and reads its pages with one gather at (layer, block
+    table); block_table: (B, max_pages) int32; seq_lens: (B,) tokens
+    already written per slot (the new token lands at that position,
+    exactly like the dense path's ``cache_len``). Returns (y, (k_pages,
+    v_pages)).
 
     The engine guarantees host-side that every active slot's write-target
     page is exclusively owned (copy-on-write happens before the step), so
     the scatter never mutates a page another slot can read.
 
     ``shard`` (inside ``jax.shard_map`` only): KV heads are striped
-    over ``shard.model_axis`` — this shard's page slabs hold KV/m heads.
+    over ``shard.model_axis`` — this shard's page slabs hold KV/m heads
+    (a contiguous KV/m·Dh stripe of the width).
     The projections compute the FULL head set (replicated math, so every
     per-head value is bitwise the single-device one), this shard's head
     slice is written/attended locally (attention is head-local: no
@@ -393,6 +398,8 @@ def paged_attention_block(x, p, cfg: ModelConfig, dist: Dist, *,
     an exact recombination, never a partial-sum reduce.
     """
     from repro.kernels.paged_attention.ops import paged_decode_attention
+    from repro.kernels.paged_attention.ref import (linearise,
+                                                   paged_attention_rows)
     B, S, _ = x.shape
     assert S == 1, "paged decode is one token per slot per step"
     Hp, KV, Dh = cfg.padded_heads, cfg.n_kv_heads, cfg.head_dim
@@ -433,18 +440,29 @@ def paged_attention_block(x, p, cfg: ModelConfig, dist: Dist, *,
         k = jax.lax.dynamic_slice_in_dim(k, mi * kv_loc, kv_loc, 2)
         v = jax.lax.dynamic_slice_in_dim(v, mi * kv_loc, kv_loc, 2)
 
-    # scatter the new K/V row into each slot's tail page (inactive slots
-    # target the null page 0 — always masked, never read)
-    pt = k_pages.shape[1]
+    # scatter the new K/V row into each slot's tail page at this layer
+    # (inactive slots target the null page 0 — always masked, never read)
+    pt = k_pages.shape[2]
     pidx = jnp.clip(pos // pt, 0, block_table.shape[1] - 1)
     pids = block_table[jnp.arange(B), pidx]
     offs = pos % pt
-    k_pages = k_pages.at[pids, offs].set(k[:, 0])
-    v_pages = v_pages.at[pids, offs].set(v[:, 0])
+    k_pages = k_pages.at[layer, pids, offs].set(k[:, 0].reshape(B, -1))
+    v_pages = v_pages.at[layer, pids, offs].set(v[:, 0].reshape(B, -1))
 
-    o = paged_decode_attention(q[:, 0], k_pages, v_pages, block_table,
-                               pos + 1, qh2kv=qh2kv, window=window,
-                               use_pallas=use_pallas)
+    kv_loc = k.shape[2]
+    if use_pallas:
+        # the kernel takes one layer's (P, page, KV, Dh) pages
+        def heads(slab):
+            return slab[layer].reshape(slab.shape[1], pt, kv_loc, Dh)
+        o = paged_decode_attention(q[:, 0], heads(k_pages), heads(v_pages),
+                                   block_table, pos + 1, qh2kv=qh2kv,
+                                   window=window, use_pallas=True)
+    else:
+        def rows(slab):
+            return linearise(slab, block_table, layer).reshape(
+                B, -1, kv_loc, Dh)
+        o = paged_attention_rows(q[:, 0], rows(k_pages), rows(v_pages),
+                                 pos + 1, qh2kv=qh2kv, window=window)
     if n_model > 1:
         # exact head-concatenating combine: each head's value comes from
         # exactly one shard, so the recombined o is bitwise the oracle's
@@ -717,6 +735,7 @@ def paged_mla_block(x, p, cfg: ModelConfig, *, c_pages, pe_pages, layer,
     seq_lens (B,) tokens already written per slot (the new token lands
     there; inactive slots point at the null page). Returns (y, (c_pages,
     pe_pages))."""
+    from repro.kernels.paged_attention.ref import linearise
     m = cfg.mla
     B = x.shape[0]
     H = cfg.n_heads
@@ -731,9 +750,8 @@ def paged_mla_block(x, p, cfg: ModelConfig, *, c_pages, pe_pages, layer,
         c_new[:, 0].astype(c_pages.dtype))
     pe_pages = pe_pages.at[layer, pids, offs].set(
         pe_new[:, 0].astype(pe_pages.dtype))
-    c_kv = c_pages[layer][block_table].reshape(B, n * pt, m.kv_lora_rank)
-    k_pe = pe_pages[layer][block_table].reshape(B, n * pt,
-                                                m.qk_rope_head_dim)
+    c_kv = linearise(c_pages, block_table, layer)      # (B, n·pt, rank)
+    k_pe = linearise(pe_pages, block_table, layer)     # (B, n·pt, rope)
     w_b = p["wkv_b"].reshape(m.kv_lora_rank, H,
                              m.qk_nope_head_dim + m.v_head_dim)
     w_uk, w_uv = jnp.split(w_b, [m.qk_nope_head_dim], axis=-1)

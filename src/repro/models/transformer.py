@@ -679,11 +679,13 @@ def decode_step_paged(params, tokens, k_pages, v_pages, block_table,
                       shard=None):
     """One continuous-batching decode iteration over the PAGED substrate.
 
-    tokens: (B, 1); k_pages/v_pages: (L, P, page, KV, Dh) — the shared
-    device page store, stacked on the layer axis so it rides the layer
-    scan as xs exactly like the dense arena does; block_table: (B,
-    max_pages) int32 (0-padded with the null page); seq_lens: (B,) tokens
-    already written per slot. Returns (logits (B, 1, V), k_pages,
+    tokens: (B, 1); k_pages/v_pages: (L, P, page, KV·Dh) — the shared
+    device page store, stacked on the layer axis and lane-dense; it rides
+    the layer scan whole in the carry, with the layer index, so that each
+    layer writes only its B new rows in place and gathers only the pages
+    its table names (donate both slabs to the jitted step); block_table:
+    (B, max_pages) int32 (0-padded with the null page); seq_lens: (B,)
+    tokens already written per slot. Returns (logits (B, 1, V), k_pages,
     v_pages) — the block table and lengths are host-managed by the
     engine (growth, COW, slot free), not traced state.
 
@@ -707,10 +709,10 @@ def decode_step_paged(params, tokens, k_pages, v_pages, block_table,
     p_f = params["moe"] if use_moe else params["mlp"]
 
     def block(carry, xs_):
-        x, aux = carry
-        p_a, p_fl, kp, vp = xs_
+        x, aux, kp, vp = carry
+        p_a, p_fl, layer = xs_
         y, (kp, vp) = paged_attention_block(
-            x, p_a, cfg, dist, k_pages=kp, v_pages=vp,
+            x, p_a, cfg, dist, k_pages=kp, v_pages=vp, layer=layer,
             block_table=block_table, seq_lens=seq_lens,
             use_pallas=use_pallas, window_override=window_override,
             shard=shard)
@@ -718,13 +720,14 @@ def decode_step_paged(params, tokens, k_pages, v_pages, block_table,
         y, a = _ffn(x, p_fl, p_fl, cfg, dist, use_moe)
         x = x + y
         x = dist.constrain(x, dist.residual_spec(x.shape[1]))
-        return (x, aux + a), (kp, vp)
+        return (x, aux + a, kp, vp), None
 
-    (x, aux), (kps, vps) = jax.lax.scan(
-        block, (x, 0.0), (params["attn"], p_f, k_pages, v_pages))
+    (x, aux, k_pages, v_pages), _ = jax.lax.scan(
+        block, (x, 0.0, k_pages, v_pages),
+        (params["attn"], p_f, jnp.arange(cfg.n_layers)))
     h = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = _logits_at(params, h, cfg)
-    return logits, kps, vps
+    return logits, k_pages, v_pages
 
 
 def decode_step_paged_mla(params, tokens, c_pages, pe_pages, block_table,
@@ -795,7 +798,7 @@ def decode_step_paged_sharded(params, tokens, k_pages, v_pages, block_table,
     mesh: decode slots data-parallel (tokens / block table / seq_lens
     shard by row; every per-slot op is row-independent, so each data
     shard's math is bitwise the full-batch math), KV heads model-parallel
-    (each model shard holds (L, P, page, KV/m, Dh) page-slab stripes; the
+    (each model shard holds (L, P, page, KV/m·Dh) page-slab stripes; the
     inner attention loop is all_gather/psum-free because attention is
     head-local, and the only model-axis collective is the exact
     head-concatenating combine ahead of the output projection inside
@@ -815,7 +818,7 @@ def decode_step_paged_sharded(params, tokens, k_pages, v_pages, block_table,
     if reason:
         raise ValueError(f"cannot shard paged decode over {d}x{m}: {reason}")
     shard = PagedShard("model", m)
-    pages_spec = P(None, "data", None, "model", None)
+    pages_spec = P(None, "data", None, "model")
 
     def local_step(p, t, kp, vp, tbl, lens):
         return decode_step_paged(p, t, kp, vp, tbl, lens, cfg,

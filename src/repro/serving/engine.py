@@ -674,12 +674,15 @@ def paged_supported(cfg: ModelConfig) -> bool:
 def _device_tokens(x, lo, n: int):
     """Tokens [lo, lo + n) of device KV, (L, S, ...) or a B=1 dense cache
     (L, 1, S, ...), told apart by its unit batch axis (a one-token run in
-    the first layout reads as the second), as (L, n, ...) with the
-    per-token width of ``cfg.kv_widths``. ``lo`` is traced: one program
-    per (S, n) serves a block wherever it lies in the prompt."""
+    the first layout reads as the second), as lane-dense rows (L, n,
+    width): each token's ``cfg.kv_widths`` flattened, as a page slab
+    holds it, so the copy that takes the slice also lays it out and the
+    page write moves rows as they are. ``lo`` is traced: one program per
+    (S, n) serves a block wherever it lies in the prompt."""
     if x.shape[1] == 1:
         x = x.reshape(x.shape[:1] + x.shape[2:])
-    return jax.lax.dynamic_slice_in_dim(x, lo, n, axis=1)
+    x = jax.lax.dynamic_slice_in_dim(x, lo, n, axis=1)
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 def _kv_tokens(k, v, lo: int, hi: int):
@@ -925,11 +928,13 @@ class PrefillWorker:
         host = iter(jax.device_get([
             (_device_tokens(kv.k, a, b - a), _device_tokens(kv.v, a, b - a))
             for cut in cuts for a, b in cut]))
+        wk, wv = self.cfg.kv_widths
         out = []
         for cut in cuts:
             ks, vs = zip(*(next(host) for _ in cut))
-            out.append((np.concatenate(ks, axis=1),
-                        np.concatenate(vs, axis=1)))
+            k, v = np.concatenate(ks, axis=1), np.concatenate(vs, axis=1)
+            out.append((k.reshape(k.shape[:2] + wk),
+                        v.reshape(v.shape[:2] + wv)))
         return out
 
     def _put(self, hash_ids, k, v, i: int, j: int) -> None:
@@ -1345,9 +1350,7 @@ class DecodeWorker:
                 # standalone sizing (per bank): every slot of the bank at
                 # full depth + one extra sequence of staging headroom
                 # (registry runs are evictable on top, so this bound
-                # holds under sharing too). Never sized to fill HBM:
-                # each step still copies the whole slab, so a second
-                # copy must fit beside it.
+                # holds under sharing too). Never sized to fill HBM.
                 per_seq = (max_len + page_tokens - 1) // page_tokens
                 page_pool = DevicePagePool(
                     cfg, n_pages=1 + (max_batch // d + 1) * per_seq,
@@ -1365,18 +1368,19 @@ class DecodeWorker:
             self.seq_lens = np.zeros(max_batch, np.int32)
             self.n_pages_slot = np.zeros(max_batch, np.int32)
             self.caches = None
+            # the step writes its rows into the donated slabs in place
             if mesh is None:
-                # the latent step writes its rows into the slabs in place
                 self._step_paged = jax.jit(
                     lambda p, t, kp, vp, tbl, lens: decode_step_paged(
                         p, t, kp, vp, tbl, lens, cfg, use_pallas=use_pallas),
-                    donate_argnums=(2, 3) if cfg.mla is not None else ())
+                    donate_argnums=(2, 3))
             else:
                 self._step_paged = jax.jit(
                     lambda p, t, kp, vp, tbl, lens:
                     decode_step_paged_sharded(
                         p, t, kp, vp, tbl, lens, cfg, mesh,
-                        use_pallas=use_pallas))
+                        use_pallas=use_pallas),
+                    donate_argnums=(2, 3))
         else:
             self.page_pool = None
             self.mesh = None
@@ -1687,7 +1691,8 @@ class DecodeWorker:
         row-local, and sub-batch rows pad to a power of two with null
         rows (len 0, table 0), which behave exactly like the full-batch
         path's inactive slots. Buckets run sequentially, threading the
-        page slabs through (their KV writes touch disjoint pages).
+        donated page slabs through the pool (their KV writes touch
+        disjoint pages).
         Returns per-slot next tokens (B,) int32 (0 for inactive slots —
         same as don't-care argmax noise in the full path)."""
         pp = self.page_pool
@@ -1695,7 +1700,6 @@ class DecodeWorker:
         needs = {i: int(self.seq_lens[i]) // pt + 1 for i in active}
         plan = plan_width_buckets(list(needs.values()), self.max_pages,
                                   self.width_buckets)
-        kp, vp = pp.k_pages, pp.v_pages
         toks_host = np.asarray(self.tokens)
         nxt = np.zeros(self.max_batch, np.int32)
         for w in plan:
@@ -1712,15 +1716,14 @@ class DecodeWorker:
             tbl[:len(rows)] = self.block_table[rows][:, :w]
             lens = np.zeros(nr, np.int32)
             lens[:len(rows)] = self.seq_lens[rows]
-            logits, kp, vp, *touched = self._step_paged(
-                self.params, jnp.asarray(toks), kp, vp,
+            logits, pp.k_pages, pp.v_pages, *touched = self._step_paged(
+                self.params, jnp.asarray(toks), pp.k_pages, pp.v_pages,
                 jnp.asarray(tbl), jnp.asarray(lens))
             self._note_touched(touched)
             sub = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             for j, i in enumerate(rows):
                 nxt[i] = int(sub[j])
             self.counters["bucket_substeps"] += 1
-        pp.k_pages, pp.v_pages = kp, vp
         return jnp.asarray(nxt)
 
     def step(self) -> list[tuple[int, int, bool]]:

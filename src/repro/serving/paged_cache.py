@@ -4,9 +4,10 @@ these pages, step 2 stores new pages back).
 
 Layout (per attention layer stacked on a leading axis):
 
-    k_pages, v_pages : (L, n_pages, page_tokens) + ``cfg.kv_widths``:
-                       (KV, Dh) each, or latent attention's c_kv (rank,)
-                       and k_pe (rope,) with no head axis
+    k_pages, v_pages : ``PagedKVCache``: (L, n_pages, page_tokens) +
+                       ``cfg.kv_widths``; ``DevicePagePool``: (L, n_pages,
+                       page_tokens, width), lane-dense — width KV·Dh, or
+                       latent attention's c_kv rank and k_pe rope width
     block_table      : (B, max_pages_per_seq) int32 — page id per slot
     seq_lens         : (B,) int32
 
@@ -31,6 +32,7 @@ Two tiers of API live here:
 """
 from __future__ import annotations
 
+import math
 import threading
 from dataclasses import dataclass, field
 from typing import Optional
@@ -204,20 +206,22 @@ def gather_kv(cache: PagedKVCache, max_tokens: int):
 def page_write(page_tokens: int, *, per_page: bool, sharding=None):
     """The jitted page write behind ``DevicePagePool.write_run``:
     ``(k_pages, v_pages, idx, k, v) -> (k_pages, v_pages)`` with (L, T,
-    ...) K and V (each its slab's per-token width) written into the pages
-    ``idx`` (a partial tail page zero-padded). Both slabs are donated, so the write happens in place;
-    on a mesh, ``sharding`` is pinned as the slabs' output sharding.
+    ...) K and V (lane-dense rows as ``write_run`` passes them, or by
+    token) written into the pages ``idx`` of the (L, P, page, width)
+    slabs (a partial tail page zero-padded). Both slabs are donated, so
+    the write happens in place; on a mesh, ``sharding`` is pinned as the
+    slabs' output sharding.
 
     ``per_page``: one ``dynamic_update_slice`` per page, which touches
     only that page where pages are contiguous in the slab's layout.
     Otherwise one scatter: where the device layout puts the page axis
     minor-most, every page is strided over the whole slab, and one pass
-    beats a pass per page. On a TPU v5e: 1.0 against 17.6 ms per
-    8-page write into qwen2.5-3b's slabs (pages major), 26 against 333 ms
-    into phi3-mini's (pages minor). One flag for both slabs, or a (k, v)
-    pair where their layouts differ (a latent pair's 64-wide ``k_pe``
-    slab puts its pages minor-most, its 512-wide ``c_kv`` slab does
-    not)."""
+    beats a pass per page. On a TPU v5e, into (L, P, page, KV, Dh)
+    slabs: 1.0 against 17.6 ms per 8-page write into qwen2.5-3b's (pages
+    major), 26 against 333 ms into phi3-mini's (pages minor; lane-dense,
+    its pages are major). One flag for both slabs, or a (k, v) pair where
+    their layouts differ (a latent pair's 64-wide ``k_pe`` slab puts its
+    pages minor-most, its 512-wide ``c_kv`` slab does not)."""
     pt = page_tokens
     pk, pv = (per_page, per_page) if isinstance(per_page, bool) \
         else per_page
@@ -225,13 +229,13 @@ def page_write(page_tokens: int, *, per_page: bool, sharding=None):
     def write(k_pages, v_pages, idx, k, v):
         n = idx.shape[0]
 
-        def paged(x):
-            x = x.astype(k_pages.dtype)
+        def paged(x, slab):
+            x = x.astype(slab.dtype)
             x = jnp.pad(x, ((0, 0), (0, n * pt - x.shape[1]))
                         + ((0, 0),) * (x.ndim - 2))
-            return x.reshape((x.shape[0], n, pt) + x.shape[2:])
+            return x.reshape((x.shape[0], n, pt) + slab.shape[3:])
 
-        k, v = paged(k), paged(v)
+        k, v = paged(k, k_pages), paged(v, v_pages)
         if not pk:
             k_pages = k_pages.at[:, idx].set(k)
         if not pv:
@@ -259,9 +263,13 @@ class DevicePagePool:
     HBM that both ``PrefillWorker`` (writes fresh pages, §3 step 2) and
     ``DecodeWorker`` (attends them through block tables, §3 step 4) share.
 
-    * ``k_pages``/``v_pages``: (L, P, page_tokens) + ``cfg.kv_widths``;
-      page 0 is the reserved null page (block tables pad with it, reads of it are
-      always masked).
+    * ``k_pages``/``v_pages``: (L, P, page_tokens, width), lane-dense:
+      each token's ``cfg.kv_widths`` flattened (KV·Dh, or a latent
+      width), so a layer's rows are a contiguous run the decode step can
+      write and gather in place. Every API here takes and returns K and V
+      by token, (L, T) + ``cfg.kv_widths``; the flattening stays inside.
+      Page 0 is the reserved null page (block tables pad with it, reads of
+      it are always masked).
     * per-page REFCOUNTS (host side): a page is held by the hash-run
       registry and/or by block-table rows / staged prefill results.
       ``release`` at refcount 0 returns it to the free list; below 0
@@ -277,10 +285,11 @@ class DevicePagePool:
       so in practice COW only triggers at a shared partial tail page
       (e.g. one ``PrefillResult`` joined into several slots).
     * MESH SHARDING (``mesh=(data, model)``): the page slabs become ONE
-      global array laid out under ``P(None, 'data', None, 'model', None)``
+      global array laid out under ``P(None, 'data', None, 'model')``
       — the page axis splits into per-data-shard BANKS of ``n_pages``
-      pages each (so capacity scales ×data) and the KV-head axis stripes
-      over the model axis (so per-device slab bytes shrink ÷model). Every
+      pages each (so capacity scales ×data) and the width stripes over
+      the model axis, KV % model == 0, so each stripe is whole KV heads
+      (so per-device slab bytes shrink ÷model). Every
       host-side structure here stays LOGICAL and global: page ids are
       global (``bank_of`` recovers the bank, local id = global %
       ``bank_pages``, and global ids ≡ 0 mod ``bank_pages`` are each
@@ -298,7 +307,7 @@ class DevicePagePool:
                 f"page_tokens={page_tokens} must divide the pool block "
                 f"({BLOCK_TOKENS} tokens) so a block maps to a page run")
         La, KV = cfg.attention_layers, cfg.n_kv_heads
-        wk, wv = cfg.kv_widths
+        wk, wv = (math.prod(w) for w in cfg.kv_widths)
         self.cfg = cfg
         self.page_tokens = page_tokens
         self.mesh = mesh
@@ -315,17 +324,17 @@ class DevicePagePool:
         self.n_banks = d
         self.bank_pages = n_pages       # per-bank budget incl. its null page
         total = d * n_pages
-        shape = (La, total, page_tokens) + wk
+        shape = (La, total, page_tokens, wk)
         if mesh is None:
             self._sharding = None
             self.k_pages = jnp.zeros(shape, DTYPE)
-            self.v_pages = jnp.zeros((La, total, page_tokens) + wv, DTYPE)
+            self.v_pages = jnp.zeros((La, total, page_tokens, wv), DTYPE)
         else:
             from jax.sharding import NamedSharding, PartitionSpec
             self._sharding = NamedSharding(
-                mesh, PartitionSpec(None, "data", None, "model", None))
+                mesh, PartitionSpec(None, "data", None, "model"))
             self._src_sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, "model", None))
+                mesh, PartitionSpec(None, None, "model"))
             self.k_pages = jax.device_put(jnp.zeros(shape, DTYPE),
                                           self._sharding)
             self.v_pages = jax.device_put(jnp.zeros(shape, DTYPE),
@@ -571,19 +580,24 @@ class DevicePagePool:
 
     # ---- device writes -------------------------------------------------
     def write_run(self, pages: list[int], k, v) -> None:
-        """Scatter (L, T, ...) K and V into ``pages`` (T ≤ len(pages)·page).
-        ``k``/``v`` are host arrays or device arrays (a prefill's dense
-        cache, sliced). One jitted write (``page_write``) that donates both
-        slabs, so the pages are written in place; a partial tail page is
-        zero-padded (fresh pages, nothing to preserve). Compiles once per
-        (pages, T) shape, whatever the pages' ids or where the KV came
-        from."""
+        """Scatter (L, T) + ``cfg.kv_widths`` K and V, or the same as
+        lane-dense rows (L, T, width), into ``pages`` (T ≤
+        len(pages)·page). ``k``/``v`` are host arrays or device arrays (a
+        prefill's dense cache, sliced). One jitted write (``page_write``)
+        of (L, T, width) rows that donates both slabs, so the pages are
+        written in place; a partial tail page is zero-padded (fresh pages,
+        nothing to preserve). Compiles once per (pages, T) shape, whatever
+        the pages' ids or where the KV came from."""
         assert k.shape[1] <= len(pages) * self.page_tokens, \
             (k.shape, len(pages), self.page_tokens)
+        # rows by token (free on the host; on the device a source that is
+        # not yet lane-dense is laid out once here)
+        k = k.reshape(k.shape[:2] + (-1,))
+        v = v.reshape(v.shape[:2] + (-1,))
         idx = np.asarray(pages, np.int32)
         if self._sharding is not None:
             # a device source may sit on another device than the slabs:
-            # lay it out as the slabs stripe their KV heads
+            # lay it out as the slabs stripe their width (whole KV heads)
             k = jax.device_put(k, self._src_sharding)
             v = jax.device_put(v, self._src_sharding)
         with self._lock:
@@ -631,7 +645,7 @@ class DevicePagePool:
     def import_run(self, k: np.ndarray, v: np.ndarray,
                    n_tokens: int, bank: int = 0) -> list[int]:
         """Promote host KV back into device pages: alloc a fresh run in
-        one bank and scatter ``(L, n_tokens, KV, Dh)`` into it. The
+        one bank and scatter ``(L, n_tokens) + cfg.kv_widths`` into it. The
         caller owns one reference per returned page (the inverse of
         ``export_run``; the registry is NOT touched — use ``stage_run``
         to re-share full blocks). Raises ``MemoryError`` holding
@@ -648,13 +662,15 @@ class DevicePagePool:
 
     # ---- host-side reads (oracle/debug) --------------------------------
     def read_seq(self, pages: list[int], n_tokens: int):
-        """Gather one sequence's contiguous (L, n_tokens, ...) K and V."""
+        """Gather one sequence's contiguous (L, n_tokens) +
+        ``cfg.kv_widths`` K and V."""
         idx = jnp.asarray(pages, jnp.int32)
         L = self.k_pages.shape[0]
-        k = self.k_pages[:, idx]            # (L, n, pt, KV, Dh)
+        wk, wv = self.cfg.kv_widths
+        k = self.k_pages[:, idx]            # (L, n, pt, width)
         v = self.v_pages[:, idx]
-        k = k.reshape(L, -1, *k.shape[3:])[:, :n_tokens]
-        v = v.reshape(L, -1, *v.shape[3:])[:, :n_tokens]
+        k = k.reshape(L, -1, *wk)[:, :n_tokens]
+        v = v.reshape(L, -1, *wv)[:, :n_tokens]
         return np.asarray(k), np.asarray(v)
 
     def stats(self) -> dict:

@@ -19,6 +19,7 @@ from repro.kernels.paged_attention.ref import (paged_attention_layers_ref,
 from repro.serving.engine import (DecodeWorker, HostKVPool, PrefillWorker,
                                   PrefixHasher, prefix_hash_ids)
 from repro.serving.paged_cache import DevicePagePool
+from repro.serving.request import ServingRequest
 
 CFG = get_config("smollm-360m").reduced()
 KEY = jax.random.PRNGKey(0)
@@ -295,6 +296,73 @@ def test_paged_matches_dense_with_prefix_sharing(setup):
     assert dw.stats()["zero_copy_joins"] == 3      # adoption, no dense copy
     assert pp.stats()["shared_adoptions"] >= 2     # reqs 2,3 shared 2 blocks
     pp.check_leaks()
+
+
+@pytest.mark.parametrize("heads", ["grouped", "qh2kv"])
+def test_carried_step_matches_dense(heads):
+    """The paged step, its lane-dense slabs carried whole through the
+    layer scan and each layer's rows written and gathered by index,
+    emits the dense arena's tokens bit for bit: grouped GQA (16 query
+    heads over 2 KV heads), and the explicit query→KV head map of 4
+    query heads padded to 16."""
+    import dataclasses
+    from repro.models.layers import GROUPED_ATTN
+    from repro.models.transformer import init_params
+    cfg = CFG if heads == "qh2kv" else dataclasses.replace(CFG, n_heads=16)
+    grouped = GROUPED_ATTN and cfg.padded_heads == cfg.n_heads
+    assert grouped == (heads == "grouped")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(6)
+    reqs = [rng.integers(0, cfg.vocab_size, n) for n in (70, 600, 130)]
+    L, KV, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    outs = {}
+    for substrate in ("paged", "dense"):
+        pw = PrefillWorker(params, cfg, HostKVPool(), prefill_chunk=256)
+        dw = DecodeWorker(params, cfg, max_batch=4, max_len=1024,
+                          substrate=substrate)
+        if substrate == "paged":
+            assert dw.page_pool.k_pages.shape[::2] == (L, 64)
+            assert dw.page_pool.k_pages.shape[3] == KV * Dh
+        got = {}
+        for i, t in enumerate(reqs):
+            r = pw(t)
+            dw.join(ServingRequest(req_id=i, tokens=t, max_new=5), r)
+            got[i] = [r.first_token]
+        while dw.n_active:
+            for rid, tok, _ in dw.step():
+                got[rid].append(tok)
+        outs[substrate] = got
+    assert outs["paged"] == outs["dense"]
+
+
+def test_lane_dense_pool_round_trips_bit_exact():
+    """The pool keeps (L, P, page, KV·Dh) slabs and takes and gives K and
+    V by token: ``write_run`` then ``read_seq``, from host arrays and from
+    lane-dense device rows, and ``export_run`` then ``import_run`` give
+    back the written bytes (in the pool's dtype) exactly."""
+    pool = _tiny_pool(n_pages=24)
+    L, KV, Dh = CFG.attention_layers, CFG.n_kv_heads, CFG.head_dim
+    assert pool.k_pages.shape == (L, 24, 64, KV * Dh)
+    n = 150                                  # 3 pages, a partial tail
+    k, v = _rand_kv(n, seed=8)
+    want = [np.asarray(jnp.asarray(x, pool.k_pages.dtype)) for x in (k, v)]
+    run = pool.alloc(pool.pages_for(n))
+    pool.write_run(run, k, v)
+    got = pool.read_seq(run, n)
+    for g, w in zip(got, want):
+        assert g.shape == (L, n, KV, Dh)
+        np.testing.assert_array_equal(g, w)
+    rows = pool.alloc(pool.pages_for(n))
+    pool.write_run(rows, *(jnp.asarray(x).reshape(L, n, KV * Dh)
+                           for x in want))
+    for g, w in zip(pool.read_seq(rows, n), want):
+        np.testing.assert_array_equal(g, w)
+    back = pool.import_run(*pool.export_run(run, n), n)
+    for g, w in zip(pool.read_seq(back, n), want):
+        np.testing.assert_array_equal(g, w)
+    pool.release(rows)
+    pool.release(back)
+    pool.check_leaks()
 
 
 def test_slots_leaving_release_pages(setup):

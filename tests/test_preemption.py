@@ -68,7 +68,8 @@ def test_export_run_roundtrip_transfers_ownership(setup):
     cfg, params = setup
     pp = DevicePagePool(cfg, n_pages=32, page_tokens=64)
     rng = np.random.default_rng(0)
-    L, _, _, KV, Dh = pp.k_pages.shape
+    L = pp.k_pages.shape[0]
+    (KV, Dh), _ = cfg.kv_widths             # by token; the slab is lane-dense
     n_tokens = 150                          # 3 pages, partial tail
     k = rng.standard_normal((L, n_tokens, KV, Dh)).astype(np.float32)
     v = rng.standard_normal((L, n_tokens, KV, Dh)).astype(np.float32)
